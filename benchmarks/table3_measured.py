@@ -59,7 +59,10 @@ def run() -> Dict:
     env = dict(os.environ)
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(here, "src") + os.pathsep + env.get("PYTHONPATH", "")
+    # four fake CPU devices; JAX_PLATFORMS=cpu keeps the child off the
+    # accelerator, which the parent process may already hold
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _SUB], env=env,
                          capture_output=True, text=True, timeout=900)
     if out.returncode != 0:

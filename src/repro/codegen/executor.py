@@ -140,17 +140,10 @@ def _box_index(t: Transfer) -> Tuple[slice, ...]:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of jax.experimental across JAX versions (and
-    check_vma was called check_rep); pick whichever this JAX provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` without the varying-manual-axes check: worker
+    branches switch on ``axis_index``, which that check cannot type."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )
 
 

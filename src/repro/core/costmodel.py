@@ -22,6 +22,7 @@ from repro.core.graph import DAG
 __all__ = [
     "HardwareSpec",
     "TPU_V5E",
+    "hardware_for",
     "OpCost",
     "annotate",
     "box_bytes",
@@ -102,6 +103,25 @@ TPU_V5E = HardwareSpec(
     hbm_bytes=16 * 2**30,
     vmem_bytes=128 * 2**20,
 )
+
+# HardwareSpec of each accelerator by its ``jax.Device.device_kind``
+HARDWARE_BY_KIND: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The spec that prices plans for a device of this kind.  A kind with
+    no entry is an error: pricing it as another chip would hand the
+    scheduler, the slicer and the WCET certificates wrong costs."""
+    try:
+        return HARDWARE_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {device_kind!r}; known kinds: "
+            f"{sorted(HARDWARE_BY_KIND)}"
+        ) from None
+
 
 # A Keystone-II-like embedded CPU core (the paper's §5.5 target regime):
 # per-layer compute dominates inter-core UMA transfers by orders of

@@ -1,15 +1,13 @@
 """Jit'd model-facing wrappers around the Pallas kernels.
 
 These adapt model-layout tensors (GQA head grouping, [B, S, H, D] layouts)
-to the kernels' flat [BH, S, D] layout, pad sequences to block multiples,
-and fall back to interpret mode off-TPU (this container) so the same call
-sites work everywhere.
+to the kernels' flat [BH, S, D] layout and pad sequences to block
+multiples.  The kernels compile for the TPU; a caller without one passes
+``interpret=True`` (the tests do).
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -17,11 +15,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 from repro.kernels.swiglu_matmul import swiglu_matmul
 
-__all__ = ["gqa_flash_attention", "ssd_mixer", "fused_swiglu", "on_tpu"]
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+__all__ = ["gqa_flash_attention", "ssd_mixer", "fused_swiglu"]
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -41,11 +35,9 @@ def gqa_flash_attention(
     causal: bool = True,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """GQA wrapper: repeats KV per query group, flattens heads into batch."""
-    if interpret is None:
-        interpret = not on_tpu()
     B, S, H, D = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -72,11 +64,9 @@ def ssd_mixer(
     Bm: jax.Array,   # [B, S, G, N]
     Cm: jax.Array,   # [B, S, G, N]
     block_s: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Model-layout wrapper: broadcast groups to heads, flatten [B*H]."""
-    if interpret is None:
-        interpret = not on_tpu()
     B, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     rep = H // G
@@ -104,10 +94,8 @@ def fused_swiglu(
     block_m: int = 256,
     block_f: int = 256,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = not on_tpu()
     lead = x.shape[:-1]
     D = x.shape[-1]
     F = wg.shape[1]

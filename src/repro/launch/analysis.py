@@ -13,9 +13,6 @@ from typing import Any, Dict, Optional
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 from repro.configs import SHAPES, get_config, list_archs, runnable_cells, skip_reason
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.launch.mesh import make_production_mesh, mesh_shape_dict
@@ -32,15 +29,6 @@ PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9
 HBM_CAP = 16 * 2**30
-
-def _cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older
-    releases return a one-element list of dicts, newer ones a flat dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -141,7 +129,7 @@ def analyze_cell(cfg: ArchConfig, shape: ShapeSpec, mesh: jax.sharding.Mesh,
     t_compile = time.monotonic() - t0
 
     n_dev = mesh.devices.size
-    ca = _cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     bytes_acc = float(ca.get("bytes accessed", 0.0))
     try:
@@ -251,7 +239,7 @@ def validate_probe(arch: str, kind: str, mesh: jax.sharding.Mesh,
         lowered, meta = lower_cell(cfg, shape, mesh, moe_impl=moe_impl,
                                    microbatches=1, bf16_moments=False)
         compiled = lowered.compile()
-    ca = _cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     bytes_acc = float(ca.get("bytes accessed", 0.0))
     coll = sum(collective_bytes(compiled.as_text()).values())

@@ -30,11 +30,13 @@ Usage::
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SHAPES, list_archs
 from repro.launch.analysis import ART_DIR, run_cell
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=list_archs())
     ap.add_argument("--shape", choices=list(SHAPES))

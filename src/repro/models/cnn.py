@@ -16,6 +16,7 @@ standing in for the paper's OTAWA bounds (DESIGN §2).
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -155,7 +156,9 @@ class CNNModel:
     def init_params(self, key: jax.Array) -> Dict[str, Dict[str, jax.Array]]:
         params: Dict[str, Dict[str, jax.Array]] = {}
         for l in self.layers:
-            k = jax.random.fold_in(key, hash(l.name) % (2**31))
+            # crc32, not hash(): str hashes change with PYTHONHASHSEED, and
+            # a run and its reference in two processes must see one model
+            k = jax.random.fold_in(key, zlib.crc32(l.name.encode()) % (2**31))
             if l.op == "conv":
                 a = l.attrs
                 cin = a["in_shape"][2]

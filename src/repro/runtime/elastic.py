@@ -8,8 +8,9 @@ Components
 ----------
 * :class:`HealthMonitor` — heartbeats + per-step timing.  A worker is
   **dead** after ``heartbeat_timeout`` without a beat and a **straggler**
-  when its step time exceeds ``straggler_factor`` × the rolling median of
-  the fleet (the classic z-ish test used by large-scale trainers).
+  when its step time (or, against a plan, its slowdown) exceeds
+  ``straggler_factor`` × the rolling median of the fleet (the classic
+  z-ish test used by large-scale trainers).
 * :class:`ElasticPlanner` — turns a health verdict into a new plan:
   the surviving worker set is re-meshed, and — this is the paper's loop
   closed — the *same offline DAG scheduler* that produced the original
@@ -86,9 +87,21 @@ class HealthMonitor:
     def heartbeat(self, worker: int, t: Optional[float] = None) -> None:
         self.workers[worker].last_heartbeat = self.now if t is None else t
 
-    def record_step(self, step: int, dt: float, worker: int = 0) -> None:
+    def record_step(
+        self, step: int, dt: float, worker: int = 0,
+        expected: Optional[float] = None,
+    ) -> None:
+        """Record one step of ``worker``.  ``expected`` is the step's
+        planned time where the caller has a plan: the straggler test then
+        compares slowdowns (``dt / expected``), so the heavily loaded
+        worker of an imbalanced plan is not a straggler.  A step planned
+        idle (``expected == 0``) says nothing about speed and feeds only
+        the deadline timings."""
         w = self.workers[worker]
-        w.step_times.append(dt)
+        if expected is None:
+            w.step_times.append(dt)
+        elif expected > 0:
+            w.step_times.append(dt / expected)
         w.timings.append((step, dt))
         if len(w.step_times) > self.window:
             w.step_times.pop(0)

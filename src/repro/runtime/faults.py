@@ -290,7 +290,10 @@ def run_with_faults(
                 step_times[i][w] * slow.get(w, 1.0) for w in range(m)
             ]
             for w in range(m):
-                monitor.record_step(i, dts[w], worker=worker_ids[w])
+                monitor.record_step(
+                    i, dts[w], worker=worker_ids[w],
+                    expected=step_times[i][w],
+                )
             monitor.advance(max(dts) if dts else 0.0)
     barrier(len(plan.steps), needed=True)
     y = np.asarray(regs[plan.sink_worker][plan.sink])

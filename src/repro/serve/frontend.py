@@ -750,7 +750,7 @@ class Frontend:
         for i, ts in enumerate(self._step_times):
             dts = [ts[w] * slow[w] for w in range(len(self.worker_ids))]
             for w, mid in enumerate(self.worker_ids):
-                self.monitor.record_step(i, dts[w], worker=mid)
+                self.monitor.record_step(i, dts[w], worker=mid, expected=ts[w])
             self.monitor.advance(max(dts) if dts else 0.0)
         self.last_worker_times = [
             (mid, sum(ts[w] * slow[w] for ts in self._step_times))

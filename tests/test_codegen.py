@@ -195,3 +195,26 @@ for m in (2, 4):
 print("MPMD_OK")
 """, devices=4)
         assert "MPMD_OK" in out
+
+
+_PARAM_DIGEST = """
+import hashlib, jax, numpy as np
+from repro.models.cnn import inception_net, lenet5
+h = hashlib.sha256()
+for model in (lenet5(), inception_net(64)):
+    params = model.init_params(jax.random.PRNGKey(0))
+    for name in sorted(params):
+        for k in sorted(params[name]):
+            h.update(np.asarray(params[name][k]).tobytes())
+print(h.hexdigest())
+"""
+
+
+def test_params_independent_of_hash_seed(subproc, monkeypatch):
+    """A run and its reference in two processes see one model: parameters
+    must not depend on ``PYTHONHASHSEED``."""
+    digests = set()
+    for seed in ("1", "2"):
+        monkeypatch.setenv("PYTHONHASHSEED", seed)
+        digests.add(subproc(_PARAM_DIGEST).strip())
+    assert len(digests) == 1

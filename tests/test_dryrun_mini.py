@@ -16,7 +16,10 @@ from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.launch.analysis import analyze_cell
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh(
+    (2, 2, 2), ("pod", "data", "model"),
+    axis_types=(jax.sharding.AxisType.Auto,) * 3,
+)
 out = {}
 cells = [
     ("qwen2-0.5b", ShapeSpec("t", "train", 64, 8)),

@@ -47,6 +47,30 @@ def make_frontend(setup, **cfg_kw):
     return Frontend(sliced, params, dag, m=4, hw=KEYSTONE_CPU, cfg=cfg)
 
 
+def test_fault_free_imbalanced_plan_keeps_its_fleet():
+    """A fault-free run of a load-imbalanced plan (inception's searched m=4
+    DSH plan: worker 0 carries the stem) cordons no worker: stragglers are
+    judged against each worker's own planned step times."""
+    from repro.core.costmodel import TPU_V5E
+    from repro.models.cnn import inception_net
+    from repro.models.slicing import search_slice_factors
+
+    model = inception_net(64)
+    sliced = slice_model(model, search_slice_factors(model, TPU_V5E, m=4))
+    dag = sliced.to_dag(TPU_V5E, time_unit=1e-6)
+    params = model.init_params(jax.random.PRNGKey(0))
+    fe = Frontend(sliced, params, dag, m=4, hw=TPU_V5E,
+                  cfg=FrontendConfig(max_rows=1))
+    loads = [sum(ts[w] for ts in fe._step_times) for w in range(4)]
+    assert max(loads) > 1.2 * sorted(loads)[1]  # the plan is imbalanced
+    pool = input_pool(model.layers[0].out_shape, 4, seed=3)
+    trace = poisson_trace(4, seed=0, rate=0.5 / fe.est_service, rows=(1,),
+                          pool_size=4, service=fe.est_service)
+    fe.run_trace(trace, pool)
+    assert fe.recoveries == [] and fe.fleet == (0, 1, 2, 3)
+    assert fe.audit()["completed"] == 4
+
+
 class TestTrace:
     def test_same_seed_same_trace(self):
         a = poisson_trace(50, seed=9, rate=0.5)

@@ -118,6 +118,20 @@ class TestElastic:
         v = mon.check()
         assert v["stragglers"] == [2]
 
+    def test_straggler_judged_against_the_plan(self):
+        # an imbalanced plan: worker 0 carries 4x the load and workers 1-3
+        # idle in every other step; only a worker slower than its own plan
+        # is a straggler
+        mon = HealthMonitor(4, straggler_factor=2.0)
+        planned = [[4.0, 1.0, 1.0, 1.0], [4.0, 0.0, 0.0, 0.0]]
+        for rnd in range(4):
+            for step, ts in enumerate(planned):
+                for w in range(4):
+                    slow = 3.0 if w == 3 else 1.0
+                    mon.record_step(step, ts[w] * slow, worker=w,
+                                    expected=ts[w])
+        assert mon.check()["stragglers"] == [3]
+
     def test_remesh_resolves_schedule(self):
         from repro.core import random_dag
         dag = random_dag(20, 0.15, seed=2)
