@@ -63,9 +63,8 @@ Times the fast-path pipeline across DAG sizes and worker counts:
 * ``stream gate``       — the ``buffer_depth`` sweep on the same grid plan
                           (``benchmarks/stream_overlap.py``): per-depth
                           sustained supersteps/s through the serving
-                          frontend, comm/compute-overlap fraction from the
-                          ``--profile`` hooks, and the resident staging
-                          footprint; depth >= 2 must sustain
+                          frontend and the resident staging footprint;
+                          depth >= 2 must sustain
                           ``STREAM_SPEEDUP`` (1.2x) over depth 1 or beat
                           the ``STREAM_FLOOR_STEPS_S`` absolute floor (the
                           1-core CI escape, like the run gate), and

@@ -4,15 +4,11 @@ Sweeps the ``buffer_depth`` knob (1 = write-once staging, 2/4 = rotating
 double/quad-buffered staging frames + donated carry) on the grid-sliced
 inception m=8 plan and reports, per depth:
 
-* **per-segment comm/compute-overlap breakdown** — each segment's jitted
-  body is replayed in ``full`` and ``nocomm`` modes (the PR 7 ``--profile``
-  hooks), so ``full - nocomm`` is the wall time comm fails to hide.  The
-  depth-d ``overlap_frac`` is the fraction of depth-1's visible comm wall
-  time that streaming hides (0 for depth 1 by construction);
 * **peak staging bytes** — the resident staging footprint per worker
   (``peak_staging_elems`` x 4 bytes x batch), counted once globally, not
   per fire.  Depths whose footprint exceeds ``--budget-mb`` are reported
-  and skipped, the vmem/register-budget half of the sweep;
+  and skipped, the vmem/register-budget half of the sweep; the retire-copy
+  volume (``retire_elems``) beside it;
 * **sustained supersteps/s** — a seeded request trace driven through
   ``serve.Frontend`` with the executor fast path attached at that depth
   (``attach_executor(buffer_depth=d)``), timed at steady state (warm-up
@@ -74,49 +70,14 @@ def _grid_inception():
     return model, sliced, dag
 
 
-def profile_overlap(plan, sliced, params, mesh, x, depth, reps=3):
-    """Per-segment ``full``/``nocomm`` breakdown at one buffer depth.
-
-    Returns ``(rows, full_ms, comm_ms, stats)`` where ``comm_ms`` sums
-    ``max(full - nocomm, 0)`` over segments — the comm wall time the
-    schedule does *not* hide at this depth."""
-    import jax
-
+def staging_stats(plan, sliced, params, mesh, batch, depth):
+    """Static per-segment statistics of the segmented executor at one
+    buffer depth (built, not compiled)."""
     from repro.codegen.executor import build_mpmd_executor
 
-    batch = int(x.shape[0])
     f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                            segmented=True, profile=True,
-                            buffer_depth=depth)
-
-    def best(fn, *a):
-        jax.block_until_ready(fn(*a))  # warm-up = compile + 1st dispatch
-        b = None
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*a))
-            dt = time.perf_counter() - t0
-            b = dt if b is None else min(b, dt)
-        return b * 1e3
-
-    carry = f.initial_carry()
-    segs = []
-    full_ms = comm_ms = 0.0
-    for fns, st in zip(f.segment_fns, f.segment_stats):
-        t_full = best(fns["full"], carry, x)
-        t_nc = best(fns["nocomm"], carry, x)
-        segs.append({
-            "steps": list(st["steps"]),
-            "full_ms": round(t_full, 2),
-            "nocomm_ms": round(t_nc, 2),
-            "comm_visible_ms": round(max(t_full - t_nc, 0.0), 2),
-            "round_fires": st["round_fires"],
-            "retire_elems": st["retire_elems"],
-        })
-        full_ms += t_full
-        comm_ms += max(t_full - t_nc, 0.0)
-        carry = jax.block_until_ready(fns["full"](carry, x))
-    return segs, full_ms, comm_ms, f.segment_stats[0]
+                            segmented=True, buffer_depth=depth)
+    return f.segment_stats
 
 
 def sustained_supersteps(sliced, params, dag, m, depth, n_requests, warm):
@@ -158,7 +119,7 @@ def sustained_supersteps(sliced, params, dag, m, depth, n_requests, warm):
 
 
 def bench_stream_overlap(results, quick, budget_mb=DEPTH_BUDGET_MB):
-    """The gated depth sweep: overlap breakdown + sustained serving rate."""
+    """The gated depth sweep: staging footprint + sustained serving rate."""
     import jax
 
     m = 8
@@ -176,19 +137,16 @@ def bench_stream_overlap(results, quick, budget_mb=DEPTH_BUDGET_MB):
 
     depths = (1, 2) if quick else (1, 2, 4)
     n_req, warm = (10, 3) if quick else (30, 6)
-    base_comm = base_rate = None
+    base_rate = None
     rows_out = []
     for depth in depths:
-        segs, full_ms, comm_ms, st0 = profile_overlap(
-            plan, sliced, params, mesh, x, depth, reps=2 if quick else 3)
-        peak_bytes = st0["peak_staging_elems"] * 4 * int(x.shape[0])
+        stats = staging_stats(plan, sliced, params, mesh, int(x.shape[0]),
+                              depth)
+        peak_bytes = stats[0]["peak_staging_elems"] * 4 * int(x.shape[0])
         if peak_bytes > budget_mb * 1e6:
             print(f"stream d={depth}: staging {peak_bytes / 1e6:.1f}MB "
                   f"over the {budget_mb:.0f}MB budget — skipped")
             continue
-        if base_comm is None:
-            base_comm = max(comm_ms, 1e-9)
-        overlap = max(0.0, 1.0 - comm_ms / base_comm)
         rate, ticks = sustained_supersteps(
             sliced, params, dag, m, depth, n_req, warm)
         if base_rate is None:
@@ -200,22 +158,22 @@ def bench_stream_overlap(results, quick, budget_mb=DEPTH_BUDGET_MB):
             "buffer_depth": depth,
             "supersteps_per_s": round(rate, 1),
             "speedup_vs_depth1": round(rate / base_rate, 3),
-            "overlap_frac": round(overlap, 3),
             "peak_staging_bytes": peak_bytes,
-            "retire_elems": sum(s["retire_elems"] for s in segs),
-            "run_full_ms": round(full_ms, 1),
-            "comm_visible_ms": round(comm_ms, 1),
-            "segments": segs,
+            "retire_elems": sum(s["retire_elems"] for s in stats),
+            "segments": [
+                {"steps": list(s["steps"]), "round_fires": s["round_fires"],
+                 "retire_elems": s["retire_elems"]}
+                for s in stats
+            ],
             "serve_ticks": ticks,
         }
         results.append(row)
         rows_out.append(row)
         print(
             f"stream d={depth}: {rate:7.1f} supersteps/s "
-            f"({row['speedup_vs_depth1']:.2f}x d1)  overlap {overlap:5.1%}  "
+            f"({row['speedup_vs_depth1']:.2f}x d1)  "
             f"staging {peak_bytes / 1e6:5.2f}MB  retire "
-            f"{row['retire_elems']:6d} elems  full {full_ms:6.1f}ms "
-            f"(comm visible {comm_ms:5.1f}ms)"
+            f"{row['retire_elems']:6d} elems"
         )
 
     # acceptance: streaming must pay for itself — ratio on real multi-core

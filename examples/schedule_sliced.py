@@ -34,18 +34,16 @@ verifies they agree with the sequential reference, and reports the trace
 (lowering) time of each; on grid-sliced plans the segmented trace stays
 near layer-granularity cost while the unrolled one grows with task count.
 
-``--profile`` builds the segmented executor with per-segment profiling
-hooks and prints a runtime breakdown: for every segment, warm best-of-3
-wall time in ``full`` / ``nocomm`` / ``assemble`` modes, attributing the
-difference columns to comm rounds and kernel work, next to the segment's
+``--profile`` builds the segmented executor and prints each segment's
 static statistics (ticks, signatures, ring rounds, comm patterns, span
-coverage).
+coverage) beside the whole call's warm best-of-3 wall time.  The device
+time of each phase (assembly, kernels, comm, ...) is read from a profiler
+trace of the executor, whose ops carry named scopes (``codegen/executor.py``).
 
 ``--stream`` sweeps the segmented executor's ``buffer_depth`` knob
 (1 = write-once staging, 2/4 = rotating double/quad-buffered staging
 frames + donated carry) and prints, per depth, the carry width, resident
-staging footprint, retire-copy volume and the full/comm/kernel/assembly
-totals — the comm-compute-overlap breakdown of the streaming mode.
+staging footprint, retire-copy volume and the warm call time.
 
 ``--analyze`` runs the static concurrency analyzer (``codegen/analyze.py``)
 on the chosen plan: the happens-before hazard verdict at buffer depths
@@ -161,16 +159,13 @@ def main():
                          "segmented MPMD executors, verify both against the "
                          "sequential reference, and report trace times")
     ap.add_argument("--profile", action="store_true",
-                    help="per-segment runtime breakdown of the segmented "
-                         "executor: warm best-of-3 wall time per segment in "
-                         "full / no-comm / assembly-only modes (comm = full "
-                         "- nocomm, kernels = nocomm - assembly) next to "
-                         "the static span/round statistics")
+                    help="per-segment static span/round statistics of the "
+                         "segmented executor and its warm best-of-3 call "
+                         "time")
     ap.add_argument("--stream", action="store_true",
                     help="buffer_depth sweep {1,2,4} of the segmented "
                          "executor: per-depth carry width, staging "
-                         "footprint, retire volume and full/comm/kernel/"
-                         "assembly totals (the streaming overlap breakdown)")
+                         "footprint, retire volume and warm call time")
     ap.add_argument("--analyze", action="store_true",
                     help="static concurrency analysis of the chosen plan "
                          "(codegen/analyze.py): happens-before hazard "
@@ -328,104 +323,63 @@ def analyze_report(plan, sdag, sliced):
               f"({s['unread_elems']} elems)")
 
 
-def profile_segments(plan, sliced, params, mesh, x, ref):
-    """--profile satellite: per-segment runtime breakdown.
+def _best_ms(fn, *a, n=3):
+    """Warm best-of-``n`` wall time of ``fn(*a)`` (the first call compiles)."""
+    jax.block_until_ready(fn(*a))
+    b = None
+    for _ in range(n):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*a))
+        dt = time.perf_counter() - t0
+        b = dt if b is None else min(b, dt)
+    return b * 1e3
 
-    Replays each segment's jitted body over the stacked carry in three
-    modes — ``full`` (compute + assembly + comm), ``nocomm`` (comm rounds
-    elided) and ``assemble`` (gathers/spans only, kernels elided) — so the
-    differences attribute each segment's wall time to comm, kernels and
-    assembly.  Warm best-of-3 per mode; the carry advances through the
-    *full* mode so every segment profiles against its real input state.
-    Phase splits inherit the host's dispatch noise (single-core CI boxes
-    bounce +-30%); the per-segment ``full`` column and the totals row are
-    the trustworthy numbers."""
+
+def profile_segments(plan, sliced, params, mesh, x, ref):
+    """--profile satellite: per-segment static statistics.
+
+    Prints each segment's ticks, signatures, ring rounds, comm patterns
+    and span coverage, and the whole executor's warm best-of-3 call time.
+    Device time per phase comes from a profiler trace of the call: the
+    executor's ops carry ``seg<k>/<phase>`` named scopes."""
     batch = x.shape[0]
     f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                            segmented=True, profile=True)
+                            segmented=True)
     err = float(jnp.abs(f(x) - ref).max())
-    print(f"profiled segmented executor: max|y - sequential| = {err:.2e}")
-
-    def best(fn, *a, n=3):
-        jax.block_until_ready(fn(*a))  # warm-up = compile + 1st dispatch
-        b = None
-        for _ in range(n):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*a))
-            dt = time.perf_counter() - t0
-            b = dt if b is None else min(b, dt)
-        return b * 1e3
-
-    carry = f.initial_carry()
-    tot = {"full": 0.0, "nocomm": 0.0, "assemble": 0.0}
+    print(f"segmented executor: max|y - sequential| = {err:.2e}, "
+          f"warm call {_best_ms(f, x):.2f} ms")
     print(f"{'seg':>4} {'steps':>9} {'ticks':>5} {'sigs':>4} {'rnds':>4} "
-          f"{'pats':>4} {'cov':>5} | {'full':>8} {'comm':>8} {'kern':>8} "
-          f"{'asm':>8}  (ms)")
-    for k, (fns, st) in enumerate(zip(f.segment_fns, f.segment_stats)):
-        ts = {mode: best(fns[mode], carry, x)
-              for mode in ("full", "nocomm", "assemble")}
-        for mode in tot:
-            tot[mode] += ts[mode]
+          f"{'pats':>4} {'cov':>5}")
+    for k, st in enumerate(f.segment_stats):
         lo, hi = st["steps"]
         print(f"{k:>4} {f'{lo}-{hi}':>9} {st['ticks']:>5} {st['sigs']:>4} "
               f"{st['rounds']:>4} {st['comm_patterns']:>4} "
-              f"{st['span_coverage']:>5.2f} | {ts['full']:>8.2f} "
-              f"{ts['full'] - ts['nocomm']:>8.2f} "
-              f"{ts['nocomm'] - ts['assemble']:>8.2f} "
-              f"{ts['assemble']:>8.2f}")
-        carry = jax.block_until_ready(fns["full"](carry, x))
-    print(f"totals: full {tot['full']:.2f} ms = "
-          f"comm {tot['full'] - tot['nocomm']:.2f} "
-          f"+ kernels {tot['nocomm'] - tot['assemble']:.2f} "
-          f"+ assembly {tot['assemble']:.2f}")
+              f"{st['span_coverage']:>5.2f}")
 
 
 def stream_report(plan, sliced, params, mesh, x, ref):
-    """--stream satellite: buffer-depth sweep + overlap breakdown.
+    """--stream satellite: buffer-depth sweep.
 
-    Builds the profiled segmented executor at ``buffer_depth`` 1, 2 and 4
-    and prints each depth's carry width, resident per-worker staging
+    Builds the segmented executor at ``buffer_depth`` 1, 2 and 4 and
+    prints each depth's carry width, resident per-worker staging
     footprint (counted once, not per fire), retire-copy volume (columns
-    moved home before a rotating frame is reused) and the summed
-    full/comm/kernel/assembly wall times over all segments.  Outputs are
-    bit-identical across depths, so the sweep is purely a cost trade:
-    depth >= 2 shrinks the carry (frames rotate instead of accumulating)
-    at the price of the retire copies."""
+    moved home before a rotating frame is reused) and warm call time.
+    Outputs are bit-identical across depths, so the sweep is purely a
+    cost trade: depth >= 2 shrinks the carry (frames rotate instead of
+    accumulating) at the price of the retire copies."""
     batch = x.shape[0]
     print(f"{'depth':>5} {'width':>9} {'staging':>10} {'retire':>8} | "
-          f"{'full':>8} {'comm':>8} {'kern':>8} {'asm':>8}  (ms)")
-
-    def best(fn, *a, n=3):
-        jax.block_until_ready(fn(*a))  # warm-up = compile + 1st dispatch
-        b = None
-        for _ in range(n):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*a))
-            dt = time.perf_counter() - t0
-            b = dt if b is None else min(b, dt)
-        return b * 1e3
-
+          f"{'call':>8}  (ms)")
     for depth in (1, 2, 4):
         f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                                segmented=True, profile=True,
-                                buffer_depth=depth)
+                                segmented=True, buffer_depth=depth)
         err = float(jnp.abs(f(x) - ref).max())
         assert err < 1e-4, f"depth {depth} diverged: {err:.2e}"
-        carry = f.initial_carry()
-        width = int(carry.shape[-1])
-        tot = {"full": 0.0, "nocomm": 0.0, "assemble": 0.0}
-        for fns in f.segment_fns:
-            for mode in tot:
-                tot[mode] += best(fns[mode], carry, x)
-            carry = jax.block_until_ready(fns["full"](carry, x))
         st0 = f.segment_stats[0]
         staging = st0["peak_staging_elems"] * 4 * batch
         retire = sum(st["retire_elems"] for st in f.segment_stats)
-        print(f"{depth:>5} {width:>9} {staging / 1e6:>8.2f}MB {retire:>8} | "
-              f"{tot['full']:>8.2f} {tot['full'] - tot['nocomm']:>8.2f} "
-              f"{tot['nocomm'] - tot['assemble']:>8.2f} "
-              f"{tot['assemble']:>8.2f}")
-
+        print(f"{depth:>5} {f.width:>9} {staging / 1e6:>8.2f}MB {retire:>8} | "
+              f"{_best_ms(f, x):>8.2f}")
 
 if __name__ == "__main__":
     main()

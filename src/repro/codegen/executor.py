@@ -92,9 +92,12 @@ unrolled executor (which does static slices and exact payloads):
 
 ``span_coalesce`` / ``cohort_rounds`` / ``bake_params`` toggle their fast
 path (ablation knobs; outputs are bit-identical in every combination),
-and ``profile=True`` exposes per-segment functions + static stats
-so runtime regressions are attributable per segment and per phase
-(assembly/kernel/comm — ``examples/schedule_sliced.py --profile``).
+and the segmented executor's ops carry named scopes — ``seg<k>`` per
+segment and, inside it, ``assemble`` / ``params`` / ``kernel`` / ``land``
+/ ``retire`` / ``comm`` / ``checkpoint``, then ``output`` — so a profiler
+trace of the served program attributes device time per segment and per
+phase (``jax.named_scope`` only sets op metadata; the compiled program
+is the same).
 """
 from __future__ import annotations
 
@@ -244,7 +247,6 @@ def build_mpmd_executor(
     cohort_rounds: bool = True,
     bake_params: bool = False,
     buffer_depth: int = 1,
-    profile: bool = False,
 ) -> Callable[[jax.Array], jax.Array]:
     """Compile the plan into a jitted shard_map function ``f(x) -> y``.
 
@@ -289,9 +291,7 @@ def build_mpmd_executor(
     Outputs — and checkpoint snapshots' register region — are
     bit-identical across depths; the carry width stops growing with the
     plan's fire count and is bounded by ``buffer_depth`` × the largest
-    per-tick payload.  ``profile=True`` additionally exposes per-segment
-    jitted functions and static stats for the runtime breakdown
-    (``examples/schedule_sliced.py --profile``).
+    per-tick payload.
 
     ``checkpoint=True`` (segmented only) makes the executor additionally
     return its packed register carries at every segment boundary:
@@ -342,7 +342,7 @@ def build_mpmd_executor(
             plan, model, params, mesh, axis, batch, liveness,
             checkpoint=checkpoint, span_coalesce=span_coalesce,
             cohort_rounds=cohort_rounds, bake_params=bake_params,
-            buffer_depth=buffer_depth, profile=profile,
+            buffer_depth=buffer_depth,
         )
 
     reg_names = [l.name for l in model.layers]
@@ -1069,8 +1069,7 @@ def segment_access_tables(
 
 def _make_branch(
     sig, tab, x, batch: int, gin_kinds, pidx_identity: bool,
-    const_pops=None,
-    mode: str = "full", wseg: int = 1, idle_st: int = 0,
+    const_pops=None, wseg: int = 1,
 ):
     """One switch branch: assemble the signature's input blocks from the
     packed buffer through the occurrence's index tables, run the shared
@@ -1096,83 +1095,74 @@ def _make_branch(
     ``pidx_identity`` elides the parameter-dedup indirection when every
     occurrence carries distinct parameters anyway.
 
-    ``mode="assemble"`` (profiling only) stops after input assembly and
-    folds a sum of the gathered blocks into the idle column (so the
-    compiler cannot elide the gathers) — isolating assembly cost from
-    kernel + comm in the per-segment runtime breakdown."""
+    The branch's phases are named scopes (``assemble``, ``params``,
+    ``kernel``, ``land``), so a device trace attributes its ops by phase."""
     from repro.codegen.segment import make_kernel
 
     kern = make_kernel(sig)
     slot_shapes = sig[1]
 
     def branch(buf: jax.Array, oc):
-        ins = []
-        for j, shp in enumerate(slot_shapes):
-            kind = gin_kinds[j]
-            if kind == "rows":
-                flat = _gather_cols(buf, _take_row(tab["gin"][j], oc))
-            else:
-                _tag, lens, kinds = kind
-                g = tab["gin"][j]
-                starts = (
-                    _take_row(g["starts"], oc) if "starts" in g else None
+        with jax.named_scope("assemble"):
+            ins = []
+            for j, shp in enumerate(slot_shapes):
+                kind = gin_kinds[j]
+                if kind == "rows":
+                    flat = _gather_cols(buf, _take_row(tab["gin"][j], oc))
+                else:
+                    _tag, lens, kinds = kind
+                    g = tab["gin"][j]
+                    starts = (
+                        _take_row(g["starts"], oc) if "starts" in g else None
+                    )
+                    rem = (
+                        _gather_cols(buf, _take_row(g["rem"], oc))
+                        if "rem" in g else None
+                    )
+                    pieces = []
+                    si = ri = 0
+                    for ln, k in zip(lens, kinds):
+                        if k == "span":
+                            st = jax.lax.index_in_dim(starts, si, 0, False)
+                            si += 1
+                            # primitive bind skips traced-start
+                            # canonicalization ufuncs; starts are
+                            # non-negative by construction
+                            pieces.append(jax.lax.dynamic_slice_p.bind(
+                                buf, np.int32(0), st, slice_sizes=(batch, ln)
+                            ))
+                        else:
+                            pieces.append(
+                                jax.lax.slice(rem, (0, ri), (batch, ri + ln))
+                            )
+                            ri += ln
+                    flat = (
+                        pieces[0] if len(pieces) == 1
+                        else jax.lax.concatenate(pieces, 1)
+                    )
+                ins.append(jax.lax.reshape(flat, (batch, *shp)))
+        with jax.named_scope("params"):
+            pops = ()
+            if const_pops is not None:
+                pops = [jnp.asarray(p) for p in const_pops]
+            elif "p" in tab:
+                pi = oc if pidx_identity else _take_row(tab["pidx"], oc)
+                pops = [_take_row(p, pi) for p in tab["p"]]
+        with jax.named_scope("kernel"):
+            y = kern(x, ins, pops).astype(jnp.float32)
+            w = int(np.prod(y.shape)) // batch
+            y2 = jax.lax.reshape(y, (batch, w))
+        with jax.named_scope("land"):
+            st = _take_row(tab["out"], oc)
+            if w < wseg:
+                # self-restoring tail: read back what the uniform-width
+                # write is about to overwrite, so the pad columns keep
+                # their values
+                tail = jax.lax.dynamic_slice_p.bind(
+                    buf, np.int32(0), jax.lax.add(st, np.int32(w)),
+                    slice_sizes=(batch, wseg - w),
                 )
-                rem = (
-                    _gather_cols(buf, _take_row(g["rem"], oc))
-                    if "rem" in g else None
-                )
-                pieces = []
-                si = ri = 0
-                for ln, k in zip(lens, kinds):
-                    if k == "span":
-                        st = jax.lax.index_in_dim(starts, si, 0, False)
-                        si += 1
-                        # primitive bind skips traced-start canonicalization
-                        # ufuncs; starts are non-negative by construction
-                        pieces.append(jax.lax.dynamic_slice_p.bind(
-                            buf, np.int32(0), st, slice_sizes=(batch, ln)
-                        ))
-                    else:
-                        pieces.append(
-                            jax.lax.slice(rem, (0, ri), (batch, ri + ln))
-                        )
-                        ri += ln
-                flat = (
-                    pieces[0] if len(pieces) == 1
-                    else jax.lax.concatenate(pieces, 1)
-                )
-            ins.append(jax.lax.reshape(flat, (batch, *shp)))
-        if mode == "assemble":
-            s = jnp.float32(0)
-            for blk in ins:
-                s = s + jnp.sum(blk)
-            y_pad = jnp.broadcast_to(s, (batch, 1)).astype(jnp.float32)
-            if wseg > 1:
-                y_pad = jax.lax.concatenate([
-                    y_pad,
-                    jax.lax.slice(
-                        buf, (0, idle_st + 1), (batch, idle_st + wseg)
-                    ),
-                ], 1)
-            return y_pad, jnp.asarray(idle_st, jnp.int32)
-        pops = ()
-        if const_pops is not None:
-            pops = [jnp.asarray(p) for p in const_pops]
-        elif "p" in tab:
-            pi = oc if pidx_identity else _take_row(tab["pidx"], oc)
-            pops = [_take_row(p, pi) for p in tab["p"]]
-        y = kern(x, ins, pops).astype(jnp.float32)
-        w = int(np.prod(y.shape)) // batch
-        y2 = jax.lax.reshape(y, (batch, w))
-        st = _take_row(tab["out"], oc)
-        if w < wseg:
-            # self-restoring tail: read back what the uniform-width write
-            # is about to overwrite, so the pad columns keep their values
-            tail = jax.lax.dynamic_slice_p.bind(
-                buf, np.int32(0), jax.lax.add(st, np.int32(w)),
-                slice_sizes=(batch, wseg - w),
-            )
-            y2 = jax.lax.concatenate([y2, tail], 1)
+                y2 = jax.lax.concatenate([y2, tail], 1)
         return y2, st
 
     return branch
@@ -1191,7 +1181,6 @@ def _build_segmented(
     cohort_rounds: bool = True,
     bake_params: bool = False,
     buffer_depth: int = 1,
-    profile: bool = False,
 ) -> Callable[[jax.Array], jax.Array]:
     """Segmented lax.scan lowering of a (coalesced) plan.
 
@@ -1218,10 +1207,10 @@ def _build_segmented(
     argument (``donate_argnums``) re-initialized in-trace — so the packed
     registers and staging frames are updated in place across calls instead
     of re-materialized.  Outputs, and ``checkpoint`` snapshots' register
-    region, are bit-identical to depth 1.  ``profile=True`` additionally exposes
-    ``.segment_fns`` (per-segment jitted callables over the stacked carry,
-    in ``full`` / ``nocomm`` / ``assemble`` modes) and ``.segment_stats``
-    (static span/round tables) for the per-segment runtime breakdown.
+    region, are bit-identical to depth 1.  The executor exposes
+    ``.segment_stats`` (static span/round tables per segment); its device
+    phases are named scopes (``_run_all``), so a profiler trace of the
+    served program splits its time by segment and phase.
     """
     from repro.codegen.segment import (
         SpanTable,
@@ -1491,7 +1480,7 @@ def _build_segmented(
     sink_sz = reg_sizes[plan.sink]
     sink_shape = reg_shapes[plan.sink]
 
-    def run_segment(buf, x, meta, tabs, mode="full"):
+    def run_segment(buf, x, meta, tabs):
         """Scan one segment's ticks over the packed carry.
 
         Every per-tick write is an in-place ``dynamic_update_slice``: the
@@ -1502,28 +1491,27 @@ def _build_segmented(
         body is free of buffer copies, element scatters, and per-round
         idle conds.
 
-        ``mode``: ``"full"`` (compute + comm), ``"nocomm"`` (rounds
-        skipped), ``"assemble"`` (input assembly only — profiling)."""
+        Phases are named scopes: the branches' own (``_make_branch``),
+        ``land`` for the idle branch and the tick's write, ``retire`` and
+        ``comm``."""
         wid = jax.lax.axis_index(axis)
         (sig_list, sig_infos, deltas, lengths, single, patterns,
          lmax, wseg, idle_st, has_ret) = meta
-        br_mode = "assemble" if mode == "assemble" else "full"
 
         def idle(b, oc):
             # self-restoring no-op: read wseg columns, write them back
-            return (
-                jax.lax.slice(b, (0, idle_st), (batch, idle_st + wseg)),
-                jnp.asarray(idle_st, jnp.int32),
-            )
+            with jax.named_scope("land"):
+                return (
+                    jax.lax.slice(b, (0, idle_st), (batch, idle_st + wseg)),
+                    jnp.asarray(idle_st, jnp.int32),
+                )
 
         branches = [idle]
         for sig, info, st in zip(sig_list, sig_infos, tabs["sigs"]):
             branches.append(_make_branch(
-                sig, st, x, batch, *info, mode=br_mode,
-                wseg=wseg, idle_st=idle_st,
+                sig, st, x, batch, *info, wseg=wseg,
             ))
         rows = tabs["rows"]
-        comm = mode == "full"
 
         def body(b, tk):
             oc = _take_row(tk["occ"], wid)
@@ -1533,18 +1521,22 @@ def _build_segmented(
                 y, st = jax.lax.switch(
                     _take_row(tk["sig"], wid), branches, b, oc
                 )
-            b = jax.lax.dynamic_update_slice_p.bind(b, y, np.int32(0), st)
-            if not comm or not deltas:
+            with jax.named_scope("land"):
+                b = jax.lax.dynamic_update_slice_p.bind(
+                    b, y, np.int32(0), st
+                )
+            if not deltas:
                 return b, None
             if has_ret:
                 # rotating frames: move the reused frame's surviving
                 # occupants back to their packed columns before this
                 # tick's landing DUS clobbers them (pad lanes shuttle
                 # the dump column's don't-care bytes)
-                b = _scatter_cols(
-                    b, _take_row(tk["rdst"], wid),
-                    _gather_cols(b, _take_row(tk["rsrc"], wid)),
-                )
+                with jax.named_scope("retire"):
+                    b = _scatter_cols(
+                        b, _take_row(tk["rdst"], wid),
+                        _gather_cols(b, _take_row(tk["rsrc"], wid)),
+                    )
 
             # comm pattern switch: each branch executes exactly the ring
             # rounds active on its ticks — worker w ships to w + delta,
@@ -1583,15 +1575,16 @@ def _build_segmented(
                     return jax.lax.concatenate(mvs, 1)
                 return branch
 
-            if len(patterns) == 1:
-                mv = mk_pat(patterns[0])()
-            else:
-                mv = jax.lax.switch(
-                    tk["pat"], [mk_pat(p) for p in patterns]
+            with jax.named_scope("comm"):
+                if len(patterns) == 1:
+                    mv = mk_pat(patterns[0])()
+                else:
+                    mv = jax.lax.switch(
+                        tk["pat"], [mk_pat(p) for p in patterns]
+                    )
+                b = jax.lax.dynamic_update_slice_p.bind(
+                    b, mv, np.int32(0), tk["base"]
                 )
-            b = jax.lax.dynamic_update_slice_p.bind(
-                b, mv, np.int32(0), tk["base"]
-            )
             return b, None
 
         buf, _ = jax.lax.scan(body, buf, tabs["xs"])
@@ -1604,25 +1597,31 @@ def _build_segmented(
         )
 
     def _run_all(x: jax.Array, buf: jax.Array, tables, wid):
+        # device phases are named scopes: ``seg<k>`` per segment, with the
+        # phases of ``run_segment`` and ``checkpoint`` inside, then
+        # ``output``
         snaps: List[jax.Array] = []
-        for meta, tabs in zip(seg_meta, tables):
-            buf = run_segment(buf, x, meta, tabs)
-            if checkpoint:
-                if "mat" in tabs:
-                    src, dst = tabs["mat"]
-                    buf = _scatter_cols(
-                        buf, _take_row(dst, wid),
-                        _gather_cols(buf, _take_row(src, wid)),
-                    )
-                snaps.append(buf)
-        out = jax.lax.reshape(
-            jax.lax.slice(
-                buf, (0, sink_off), (batch, sink_off + sink_sz)
-            ),
-            (batch, *sink_shape),
-        )
-        out = jnp.where(wid == plan.sink_worker, out, 0.0)
-        out = jax.lax.psum(out, axis)
+        for k, (meta, tabs) in enumerate(zip(seg_meta, tables)):
+            with jax.named_scope(f"seg{k}"):
+                buf = run_segment(buf, x, meta, tabs)
+                if checkpoint:
+                    with jax.named_scope("checkpoint"):
+                        if "mat" in tabs:
+                            src, dst = tabs["mat"]
+                            buf = _scatter_cols(
+                                buf, _take_row(dst, wid),
+                                _gather_cols(buf, _take_row(src, wid)),
+                            )
+                    snaps.append(buf)
+        with jax.named_scope("output"):
+            out = jax.lax.reshape(
+                jax.lax.slice(
+                    buf, (0, sink_off), (batch, sink_off + sink_sz)
+                ),
+                (batch, *sink_shape),
+            )
+            out = jnp.where(wid == plan.sink_worker, out, 0.0)
+            out = jax.lax.psum(out, axis)
         return out, buf, snaps
 
     def worker_fn(x: jax.Array, tables):
@@ -1630,7 +1629,9 @@ def _build_segmented(
         out, _buf, snaps = _run_all(x, init_buf(), tables, wid)
         if checkpoint:
             # (n_segments, 1, batch, width) per worker; the worker axis is
-            # concatenated by shard_map into (n_segments, m, batch, width)
+            # concatenated by shard_map into (n_segments, m, batch, width).
+            # Left unscoped: a scope here renames instructions of the
+            # compiled multi-worker program
             return out, jnp.stack(snaps)[:, None]
         return out
 
@@ -1696,29 +1697,4 @@ def _build_segmented(
     # (migrate_registers takes exactly this (snapshot, step) pair)
     wrapped.checkpoint_steps = tuple(s.stop for s in segments)
     wrapped.segment_stats = seg_stats
-
-    if profile:
-        p_ax = jax.sharding.PartitionSpec(axis)
-
-        def make_seg_fn(k: int, mode: str):
-            def seg_worker(bufs, x, tabs):
-                b = jax.lax.squeeze(bufs, (0,))
-                b = run_segment(b, x, seg_meta[k], tabs, mode=mode)
-                return jax.lax.expand_dims(b, (0,))
-
-            f = jax.jit(_shard_map(
-                seg_worker, mesh=mesh,
-                in_specs=(p_ax, p_rep, p_rep), out_specs=p_ax,
-            ))
-            tabs_k = seg_tables[k]
-            return lambda bufs, x, _f=f, _t=tabs_k: _f(bufs, x, _t)
-
-        wrapped.segment_fns = [
-            {mode: make_seg_fn(k, mode)
-             for mode in ("full", "nocomm", "assemble")}
-            for k in range(len(segments))
-        ]
-        wrapped.initial_carry = lambda: jnp.broadcast_to(
-            init_buf(), (m, batch, width)
-        )
     return wrapped

@@ -79,6 +79,7 @@ import dataclasses
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 from repro.core.costmodel import TPU_V5E, HardwareSpec
+from repro.events import timed
 from repro.models.cnn import CNNModel, LayerSpec, _row_window, _same_pads
 
 __all__ = [
@@ -734,6 +735,7 @@ GRID_CANDIDATES: Tuple[Optional[Factor], ...] = (
 )
 
 
+@timed("/repro/plan/slice_search")
 def search_slice_factors(
     model: CNNModel,
     hw: HardwareSpec = TPU_V5E,

@@ -55,7 +55,9 @@ import heapq
 import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.codegen.plan import (
     build_plan,
@@ -64,6 +66,7 @@ from repro.codegen.plan import (
     wcet_certificate,
 )
 from repro.core.list_scheduling import dsh, ish
+from repro.events import timed
 from repro.runtime.elastic import ElasticPlanner, HealthMonitor
 from repro.runtime.faults import (
     FaultEvent,
@@ -214,13 +217,17 @@ class Frontend:
         self.hw = hw
         self.time_unit = time_unit
         heur = {"ish": ish, "dsh": dsh}[cfg.heuristic]
-        self.plan = coalesce_transfer_steps(build_plan(heur(dag, m), dag))
+        with timed("/repro/plan/schedule"):
+            sched = heur(dag, m)
+        with timed("/repro/plan/build"):
+            self.plan = coalesce_transfer_steps(build_plan(sched, dag))
         if validate:
             from repro.codegen.validate import validate_plan
 
             # deep=True: the serving plan is proved race-free /
             # sync-sufficient / donation-safe before the first request
-            validate_plan(self.plan, dag, model=model, deep=True)
+            with timed("/repro/plan/validate"):
+                validate_plan(self.plan, dag, model=model, deep=True)
         self.layout = _plan_layout(self.plan, model)
         self.worker_ids: List[int] = list(range(m))  # plan index -> monitor id
         self.cordoned: Set[int] = set()  # stragglers replanned out, still alive
@@ -239,9 +246,10 @@ class Frontend:
             out_bytes = {
                 l.name: float(np.prod(l.out_shape)) * 4 for l in model.layers
             }
-            self.certificate = wcet_certificate(
-                self.plan, dag, out_bytes, hw=hw, time_unit=time_unit
-            )
+            with timed("/repro/plan/certificate"):
+                self.certificate = wcet_certificate(
+                    self.plan, dag, out_bytes, hw=hw, time_unit=time_unit
+                )
         self.degraded = False
         self.queue: List[ServeRequest] = []
         self.ledger: Dict[int, ServeRequest] = {}
@@ -292,40 +300,42 @@ class Frontend:
         :class:`Backpressure` telling the caller when to retry.  A request
         re-submitted after backoff reuses its ledger entry (``retries``
         accumulates across attempts)."""
-        r = self.ledger.get(req.rid)
-        if r is None:
-            n_pool = len(pool)
-            x = np.stack([
-                pool[(req.pool_idx + j) % n_pool] for j in range(req.rows)
-            ])
-            r = ServeRequest(
-                rid=req.rid, rows=req.rows, pool_idx=req.pool_idx,
-                arrival=req.arrival, deadline=req.deadline, x=x,
-            )
-            self.ledger[req.rid] = r
-        if r.rows > self.cfg.max_rows:
-            self._shed(r, "too_large")
-            return r
-        now = self.now
-        if now + self.cfg.deadline_margin * self._est() > r.deadline:
-            self._shed(r, "deadline")
-            return r
-        if len(self.queue) >= self.cfg.queue_limit:
-            if r.retries >= self.cfg.max_retries:
-                self._shed(r, "backpressure")
+        with TraceAnnotation("serve.submit", rid=req.rid):
+            r = self.ledger.get(req.rid)
+            if r is None:
+                n_pool = len(pool)
+                x = np.stack([
+                    pool[(req.pool_idx + j) % n_pool] for j in range(req.rows)
+                ])
+                r = ServeRequest(
+                    rid=req.rid, rows=req.rows, pool_idx=req.pool_idx,
+                    arrival=req.arrival, deadline=req.deadline, x=x,
+                )
+                self.ledger[req.rid] = r
+            if r.rows > self.cfg.max_rows:
+                self._shed(r, "too_large")
                 return r
-            delay = min(
-                self.cfg.retry_base * (2.0 ** r.retries), self.cfg.retry_cap
-            ) * self.est_service
-            r.retries += 1
-            self.retried += 1
-            r.status = "backoff"
-            r.retry_at = now + delay
-            return Backpressure("queue_full", delay)
-        r.status = "queued"
-        r.retry_at = None
-        self.queue.append(r)
-        return r
+            now = self.now
+            if now + self.cfg.deadline_margin * self._est() > r.deadline:
+                self._shed(r, "deadline")
+                return r
+            if len(self.queue) >= self.cfg.queue_limit:
+                if r.retries >= self.cfg.max_retries:
+                    self._shed(r, "backpressure")
+                    return r
+                delay = min(
+                    self.cfg.retry_base * (2.0 ** r.retries),
+                    self.cfg.retry_cap,
+                ) * self.est_service
+                r.retries += 1
+                self.retried += 1
+                r.status = "backoff"
+                r.retry_at = now + delay
+                return Backpressure("queue_full", delay)
+            r.status = "queued"
+            r.retry_at = None
+            self.queue.append(r)
+            return r
 
     def _shed(self, r: ServeRequest, reason: str) -> None:
         r.status = "shed"
@@ -523,32 +533,41 @@ class Frontend:
     def step(self, chaos: Optional[ChaosCampaign] = None) -> int:
         """One serving tick: health check, deadline shed, admit, execute
         (recovering in place if the run is killed), complete.  Returns the
-        number of requests completed this tick."""
+        number of requests completed this tick.
+
+        Each phase is a profiler span carrying the tick (``self.runs``):
+        ``serve.health``, ``serve.admit`` and ``serve.complete`` here, the
+        executor path's own in :meth:`_exec_run`."""
         self.runs += 1
-        self._health_check()
-        self._shed_expired()
-        batch = self._admit()
-        if not batch:
-            return 0
-        x = np.concatenate([r.x for r in batch], axis=0)
+        tick = self.runs
+        with TraceAnnotation("serve.health", tick=tick):
+            self._health_check()
+            self._shed_expired()
+        with TraceAnnotation("serve.admit", tick=tick):
+            batch = self._admit()
+            if not batch:
+                return 0
+            x = np.concatenate([r.x for r in batch], axis=0)
+            faults = self._active_faults(chaos)
         t_in = self.now
-        outcome = self._execute(x, self._active_faults(chaos))
+        outcome = self._execute(x, faults)
         if outcome.status == "killed":
             outcome = self._recover(outcome, x)
-        for w in self.cordoned:
-            self.monitor.heartbeat(w)
-        y = np.asarray(outcome.output)
-        now = self.now
-        self._ewma = 0.7 * self._ewma + 0.3 * (now - t_in)
-        off = 0
-        for r in batch:
-            r.output = y[off:off + r.rows]
-            off += r.rows
-            r.finish = now
-            r.status = "done"
-            self.completed += 1
-            if now > r.deadline:
-                self.deadline_misses += 1
+        with TraceAnnotation("serve.complete", tick=tick):
+            for w in self.cordoned:
+                self.monitor.heartbeat(w)
+            y = np.asarray(outcome.output)
+            now = self.now
+            self._ewma = 0.7 * self._ewma + 0.3 * (now - t_in)
+            off = 0
+            for r in batch:
+                r.output = y[off:off + r.rows]
+                off += r.rows
+                r.finish = now
+                r.status = "done"
+                self.completed += 1
+                if now > r.deadline:
+                    self.deadline_misses += 1
         return len(batch)
 
     # ---- trace driver ------------------------------------------------- #
@@ -688,8 +707,6 @@ class Frontend:
         names the superstep each snapshot is the entering barrier of).
         Chaos runs (any injected fault) always take the runner path, which
         is the only interruptible one."""
-        import jax
-
         devices = list(jax.devices() if devices is None else devices)
         if len(devices) < self.plan.n_workers:
             raise ValueError(
@@ -714,48 +731,63 @@ class Frontend:
         key = (bucket, depth, span, cohort, bake)
         f = self._exec_cache.get(key)
         if f is None:
-            import jax
             from repro.codegen.executor import build_mpmd_executor
 
             m = self.plan.n_workers
             mesh = jax.sharding.Mesh(
                 np.asarray(self._devices[:m]), ("workers",)
             )
-            f = build_mpmd_executor(
-                self.plan, self.model, self.params, mesh, batch=bucket,
-                segmented=True, checkpoint=True, buffer_depth=depth,
-                span_coalesce=span, cohort_rounds=cohort, bake_params=bake,
-            )
+            with timed("/repro/serve/executor_build", bucket=bucket,
+                       tick=self.runs):
+                f = build_mpmd_executor(
+                    self.plan, self.model, self.params, mesh, batch=bucket,
+                    segmented=True, checkpoint=True, buffer_depth=depth,
+                    span_coalesce=span, cohort_rounds=cohort,
+                    bake_params=bake,
+                )
             self._exec_cache[key] = f
         return f, bucket
 
     def _exec_run(self, x: np.ndarray) -> RunOutcome:
+        """One run on the compiled executor, in profiler spans:
+        ``serve.dispatch`` (executor lookup, padding and the asynchronous
+        call), ``serve.wait_snapshot`` (waits for the device, then copies
+        the checkpoint snapshots to the host: the copy is queued behind
+        the computation, and waiting apart would add a round trip to every
+        request), ``serve.monitor`` (the health monitor's per-superstep
+        records) and ``serve.output``."""
+        tick = self.runs
         rows = int(x.shape[0])
-        f, bucket = self._executor(rows)
-        xp = x
-        if bucket > rows:
-            pad = np.zeros((bucket - rows, *x.shape[1:]), x.dtype)
-            xp = np.concatenate([x, pad], axis=0)
-        y, snaps = f(xp)
-        self.last_snapshot = (np.asarray(snaps), f)
+        with TraceAnnotation("serve.dispatch", tick=tick):
+            f, bucket = self._executor(rows)
+            xp = x
+            if bucket > rows:
+                pad = np.zeros((bucket - rows, *x.shape[1:]), x.dtype)
+                xp = np.concatenate([x, pad], axis=0)
+            y, snaps = f(xp)
+        with TraceAnnotation("serve.wait_snapshot", tick=tick):
+            self.last_snapshot = (np.asarray(snaps), f)
         self.exec_runs += 1
-        # clock/monitor parity with the runner: the executor gives no
-        # per-worker wall times on a simulated fleet, so the plan's own
-        # per-superstep compute times (chronic stragglers included) feed
-        # the monitor exactly as the runner would
-        slow = {
-            w: self._chronic.get(mid, 1.0)
-            for w, mid in enumerate(self.worker_ids)
-        }
-        for i, ts in enumerate(self._step_times):
-            dts = [ts[w] * slow[w] for w in range(len(self.worker_ids))]
-            for w, mid in enumerate(self.worker_ids):
-                self.monitor.record_step(i, dts[w], worker=mid, expected=ts[w])
-            self.monitor.advance(max(dts) if dts else 0.0)
-        self.last_worker_times = [
-            (mid, sum(ts[w] * slow[w] for ts in self._step_times))
-            for w, mid in enumerate(self.worker_ids)
-        ]
-        return RunOutcome(
-            status="ok", output=np.asarray(y)[:rows], snapshots={},
-        )
+        with TraceAnnotation("serve.monitor", tick=tick):
+            # clock/monitor parity with the runner: the executor gives no
+            # per-worker wall times on a simulated fleet, so the plan's own
+            # per-superstep compute times (chronic stragglers included)
+            # feed the monitor exactly as the runner would
+            slow = {
+                w: self._chronic.get(mid, 1.0)
+                for w, mid in enumerate(self.worker_ids)
+            }
+            for i, ts in enumerate(self._step_times):
+                dts = [ts[w] * slow[w] for w in range(len(self.worker_ids))]
+                for w, mid in enumerate(self.worker_ids):
+                    self.monitor.record_step(
+                        i, dts[w], worker=mid, expected=ts[w]
+                    )
+                self.monitor.advance(max(dts) if dts else 0.0)
+            self.last_worker_times = [
+                (mid, sum(ts[w] * slow[w] for ts in self._step_times))
+                for w, mid in enumerate(self.worker_ids)
+            ]
+        with TraceAnnotation("serve.output", tick=tick):
+            out = np.asarray(y)[:rows]
+        return RunOutcome(status="ok", output=out, snapshots={})

@@ -834,7 +834,7 @@ for name, (model, ffn, m) in CASES.items():
         # contract (carry width differs per depth; staging is scratch)
         h.update(np.asarray(snaps[:, :, :, :total]).tobytes())
         digests[f"{name}|{depth}"] = h.hexdigest()
-        # the profile stats count the resident staging footprint once,
+        # the segment stats count the resident staging footprint once,
         # globally — every segment reports the same peak, not a per-fire sum
         peaks = {s["peak_staging_elems"] for s in f.segment_stats}
         assert len(peaks) == 1, (name, depth, peaks)
