@@ -36,7 +36,8 @@ near layer-granularity cost while the unrolled one grows with task count.
 
 ``--profile`` builds the segmented executor and prints each segment's
 static statistics (ticks, signatures, ring rounds, comm patterns, span
-coverage) beside the whole call's warm best-of-3 wall time.  The device
+and window-gather coverage, window elements and their gather indices)
+beside the whole call's warm best-of-3 wall time.  The device
 time of each phase (assembly, kernels, comm, ...) is read from a profiler
 trace of the executor, whose ops carry named scopes (``codegen/executor.py``).
 
@@ -338,8 +339,9 @@ def _best_ms(fn, *a, n=3):
 def profile_segments(plan, sliced, params, mesh, x, ref):
     """--profile satellite: per-segment static statistics.
 
-    Prints each segment's ticks, signatures, ring rounds, comm patterns
-    and span coverage, and the whole executor's warm best-of-3 call time.
+    Prints each segment's ticks, signatures, ring rounds, comm patterns,
+    span and window-gather coverage, window elements and window indices,
+    and the whole executor's warm best-of-3 call time.
     Device time per phase comes from a profiler trace of the call: the
     executor's ops carry ``seg<k>/<phase>`` named scopes."""
     batch = x.shape[0]
@@ -349,12 +351,14 @@ def profile_segments(plan, sliced, params, mesh, x, ref):
     print(f"segmented executor: max|y - sequential| = {err:.2e}, "
           f"warm call {_best_ms(f, x):.2f} ms")
     print(f"{'seg':>4} {'steps':>9} {'ticks':>5} {'sigs':>4} {'rnds':>4} "
-          f"{'pats':>4} {'cov':>5}")
+          f"{'pats':>4} {'cov':>5} {'win':>5} {'win_elems':>10} "
+          f"{'win_idx':>8}")
     for k, st in enumerate(f.segment_stats):
         lo, hi = st["steps"]
         print(f"{k:>4} {f'{lo}-{hi}':>9} {st['ticks']:>5} {st['sigs']:>4} "
               f"{st['rounds']:>4} {st['comm_patterns']:>4} "
-              f"{st['span_coverage']:>5.2f}")
+              f"{st['span_coverage']:>5.2f} {st['window_coverage']:>5.2f} "
+              f"{st['window_elems']:>10} {st['window_indices']:>8}")
 
 
 def stream_report(plan, sliced, params, mesh, x, ref):

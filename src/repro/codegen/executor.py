@@ -41,7 +41,7 @@ tracing per-transfer slicing.  Program size is bounded by the number of
 *distinct* task structures, not the task count; results stay bit-exact
 against the unrolled path and ``interpret_plan``.
 
-Five runtime fast paths close the segmented path's per-call gap to the
+Six runtime fast paths close the segmented path's per-call gap to the
 unrolled executor (which does static slices and exact payloads):
 
 * **value-returning dispatch** — switch branches return ``(y_pad,
@@ -58,10 +58,17 @@ unrolled executor (which does static slices and exact payloads):
   pads resolved into contiguous sentinel *regions*, whole-register
   reads): each piece of at least ``segment.MIN_SPAN`` elements becomes
   one memcpy-width ``dynamic_slice`` from a per-occurrence starts table,
-  the scattered remainder shares one element gather, and only slots that
-  stay genuinely scattered (> ``segment.MAX_SPANS`` pieces or
-  < ``segment.MIN_COVERAGE`` coverage) keep the whole-slot element
-  gather;
+  the scattered remainder shares one element gather;
+* **window-gathered assembly** — a slot that stays scattered past the span
+  thresholds (> ``segment.MAX_SPANS`` pieces or < ``segment.MIN_COVERAGE``
+  coverage) but whose rows split into equal contiguous windows of at
+  least ``segment.MIN_WINDOW`` elements (``segment.coalesce_windows``:
+  an HWC channel slice, a seen-through concat's per-pixel channel runs)
+  is read from a per-occurrence table of window starts, one index per
+  window instead of per element: each window is the pair of ``LANES``-wide
+  rows that holds it, gathered from an aligned slab of the carry and
+  shifted into place (``_gather_windows``); only the remaining slots keep
+  the whole-slot element gather;
 * **staged comm with a pattern switch** — ``build_segments`` groups each
   delta's shipping ticks into payload-scale cohorts, pads each
   :class:`~repro.codegen.plan.CommRound` only to its cohort max (not the
@@ -676,6 +683,87 @@ def _gather_cols(
     )
 
 
+def _gather_windows(
+    buf: jax.Array, base: jax.Array, rel: jax.Array, length: int,
+    slab: int, sorted_: bool,
+) -> jax.Array:
+    """``concatenate([buf[:, base + r:base + r + length] for r in rel], 1)``
+    for a window table (``segment.coalesce_windows``, ``length <=
+    LANES``).
+
+    A ``lax.gather`` of ``(batch, length)`` slices would say this in one op,
+    but XLA:TPU expands any such gather wider than a few elements into a
+    loop of dynamic slices, ~1.6 us a window on a v5e.  A gather of whole
+    rows of a ``(rows, LANES)`` array is native there, so the branch views
+    the ``slab`` columns from the ``LANES``-aligned ``base`` as rows (the
+    compiler copies them into that tiling: the cost grows with ``slab``,
+    not with the carry), reads each window as the two rows that hold it,
+    and shifts it left by its offset in the first row, one bit of the
+    offset at a time (a barrel shifter of ``log2(LANES)`` selects, each
+    trimming what no later step can reach).  The slab and its spare last
+    row are in bounds by construction (``_build_segmented``)."""
+    from repro.codegen.segment import LANES
+
+    batch = buf.shape[0]
+    n = rel.shape[0]
+    view = jax.lax.reshape(
+        jax.lax.dynamic_slice_p.bind(
+            buf, np.int32(0), base, slice_sizes=(batch, slab)
+        ),
+        (batch, slab // LANES, LANES),
+    )
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(0, 2), collapsed_slice_dims=(1,), start_index_map=(1,)
+    )
+    row = jax.lax.div(rel, np.int32(LANES))
+
+    def rows_at(r):
+        return jax.lax.gather(
+            view, jax.lax.reshape(r, (n, 1)), dnums,
+            slice_sizes=(batch, 1, LANES), indices_are_sorted=sorted_,
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+        )
+
+    x = jax.lax.concatenate([rows_at(row), rows_at(row + 1)], 2)
+    off = jax.lax.rem(rel, np.int32(LANES))
+    for k in reversed(range(LANES.bit_length() - 1)):
+        d = 1 << k
+        keep = length + d - 1  # what shifts by less than d can still reach
+        bit = jax.lax.ne(
+            jax.lax.bitwise_and(off, np.int32(d)), np.int32(0)
+        )
+        x = jax.lax.select(
+            jax.lax.broadcast_in_dim(bit, (batch, n, keep), (1,)),
+            jax.lax.slice_in_dim(x, d, d + keep, axis=2),
+            jax.lax.slice_in_dim(x, 0, keep, axis=2),
+        )
+    return jax.lax.reshape(x, (batch, n * length))
+
+
+def _window_slabs(
+    starts: np.ndarray, length: int, lane_width: int
+) -> Tuple[np.ndarray, np.ndarray, int]:
+    """Slabs for a window table ``starts`` ``(n_occ, n_windows)`` read by
+    :func:`_gather_windows` from a carry ``lane_width`` columns wide (a
+    multiple of ``LANES``, past every window by at least one row).
+
+    Returns each occurrence's ``LANES``-aligned slab ``base``, the window
+    starts ``rel`` relative to it, and the ``slab`` width shared by every
+    occurrence: the smallest whole number of rows that holds each
+    occurrence's windows, plus a spare row for a window that straddles the
+    last, with a base pulled back where the slab would run past the
+    carry."""
+    from repro.codegen.segment import LANES
+
+    st = starts.astype(np.int64)
+    lo = st.min(axis=1) // LANES * LANES
+    hull = st.max(axis=1) + length - lo
+    slab = -(-int(hull.max()) // LANES) * LANES + LANES
+    base = np.minimum(lo, lane_width - slab)
+    return (base.astype(np.int32), (st - base[:, None]).astype(np.int32),
+            slab)
+
+
 def _scatter_cols(buf: jax.Array, idx: jax.Array, vals: jax.Array) -> jax.Array:
     """``buf.at[:, idx].set(vals)`` as one raw ``lax.scatter``.  Rows are
     sorted (plan-side) so XLA can lower runs to memcpys; padding entries
@@ -1091,7 +1179,11 @@ def _make_branch(
     genuinely scattered remainder (if any) is served by a single element
     gather cut up with static slices, and the pieces concatenate in row
     order.  Slots whose rows stay scattered past the coalescing thresholds
-    (``gin_kinds[j] == "rows"``) fall back to one whole-slot element gather.
+    but split into equal contiguous windows (``gin_kinds[j] == ("windows",
+    length, slab, sorted_)``) gather their windows from a per-occurrence
+    slab base and window-starts table (``_gather_windows``); the rest
+    (``gin_kinds[j] == "rows"``) fall back to one whole-slot element
+    gather.
     ``pidx_identity`` elides the parameter-dedup indirection when every
     occurrence carries distinct parameters anyway.
 
@@ -1109,6 +1201,13 @@ def _make_branch(
                 kind = gin_kinds[j]
                 if kind == "rows":
                     flat = _gather_cols(buf, _take_row(tab["gin"][j], oc))
+                elif kind[0] == "windows":
+                    _tag, ln, slab, srt = kind
+                    g = tab["gin"][j]
+                    flat = _gather_windows(
+                        buf, _take_row(g["base"], oc),
+                        _take_row(g["rel"], oc), ln, slab, srt,
+                    )
                 else:
                     _tag, lens, kinds = kind
                     g = tab["gin"][j]
@@ -1208,13 +1307,15 @@ def _build_segmented(
     registers and staging frames are updated in place across calls instead
     of re-materialized.  Outputs, and ``checkpoint`` snapshots' register
     region, are bit-identical to depth 1.  The executor exposes
-    ``.segment_stats`` (static span/round tables per segment); its device
+    ``.segment_stats`` (static span/window/round tables per segment); its device
     phases are named scopes (``_run_all``), so a profiler trace of the
     served program splits its time by segment and phase.
     """
     from repro.codegen.segment import (
+        LANES,
         SpanTable,
         coalesce_spans,
+        coalesce_windows,
         node_signature,
         param_slices,
         resolve_rows,
@@ -1282,6 +1383,11 @@ def _build_segmented(
     )
     stage_end = segments[0].stage.stage_end if segments else dump_col + 1
     width = max(stage_end, total + wmax)
+    # window gathers read LANES-aligned slabs with a spare last row: where
+    # any slot takes them, the carry grows to a whole number of rows plus
+    # one, so every slab fits inside it
+    lane_width = -(-width // LANES) * LANES + LANES
+    windowed = False
 
     sig_cache: Dict[str, Tuple] = {}
 
@@ -1329,7 +1435,7 @@ def _build_segmented(
                 occ_tab[t, w] = len(o["out"]) - 1
         sig_tabs = []
         sig_infos = []
-        span_elems = gather_elems = 0
+        span_elems = gather_elems = window_elems = window_indices = 0
         for sig, o in zip(sig_list, occs):
             n_slots = len(sig[1])
             gin = []
@@ -1356,6 +1462,10 @@ def _build_segmented(
                                 rem=np.zeros((rows.shape[0], 0), np.int32),
                                 coverage=1.0,
                             )
+                win = None
+                if span is None and span_coalesce:
+                    # scattered past the span thresholds: equal windows?
+                    win = coalesce_windows(rows)
                 gather_elems += rows.size
                 if span is not None:
                     span_elems += int(round(span.coverage * rows.size))
@@ -1364,6 +1474,16 @@ def _build_segmented(
                         g["rem"] = jnp.asarray(span.rem)
                     gin.append(g)
                     gin_kinds.append(("spans", span.lens, span.kinds))
+                elif win is not None:
+                    window_elems += rows.size
+                    window_indices += win.starts.size
+                    base, rel, slab = _window_slabs(
+                        win.starts, win.length, lane_width)
+                    gin.append({"base": jnp.asarray(base),
+                                "rel": jnp.asarray(rel)})
+                    gin_kinds.append(
+                        ("windows", win.length, slab, win.sorted_))
+                    windowed = True
                 else:
                     gin.append(jnp.asarray(rows))
                     gin_kinds.append("rows")
@@ -1474,8 +1594,15 @@ def _build_segmented(
             "span_coverage": (
                 span_elems / gather_elems if gather_elems else 1.0
             ),
+            "window_elems": window_elems,
+            "window_indices": window_indices,
+            "window_coverage": (
+                window_elems / gather_elems if gather_elems else 0.0
+            ),
         })
 
+    if windowed:
+        width = lane_width
     sink_off = offsets[plan.sink]
     sink_sz = reg_sizes[plan.sink]
     sink_shape = reg_shapes[plan.sink]

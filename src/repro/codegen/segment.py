@@ -49,6 +49,10 @@ __all__ = [
     "make_kernel",
     "SpanTable",
     "coalesce_spans",
+    "WindowTable",
+    "window_length",
+    "coalesce_windows",
+    "LANES",
     "resolve_rows",
     "max_sentinel_runs",
 ]
@@ -430,7 +434,8 @@ def make_kernel(sig: Sig) -> Callable:
 # ``dynamic_slice`` from a starts table.  Sentinel (halo-pad) entries resolve
 # to ascending positions inside pristine sentinel *regions* (see
 # :func:`resolve_rows`), so boundary tiles stay piecewise contiguous too and
-# keep sharing the interior tiles' span structure.
+# keep sharing the interior tiles' span structure.  A slot the spans leave
+# scattered may still be equal windows: see ``MIN_WINDOW`` below.
 
 # pieces at least this long become dynamic_slice spans; shorter pieces merge
 # into element-gather remainder chunks.  Every span lowers to one
@@ -444,12 +449,31 @@ def make_kernel(sig: Sig) -> Callable:
 # is flat across the range on serialized 1-core CI hosts; re-sweep on real
 # multi-core targets before tightening further.
 MIN_SPAN = 16
-# fall back to one whole-slot element gather past this many span pieces
-# (a long interleave is better served by one gather than by dozens of
+# fall back to one whole-slot gather past this many span pieces (a long
+# interleave is better served by one gather than by dozens of
 # dynamic_slice + concatenate ops)
 MAX_SPANS = 32
 # ... or when spans would cover less than this fraction of the slot
 MIN_COVERAGE = 0.4
+# A slot that does not coalesce is still usually *regular*: an HWC channel
+# slice or a seen-through concat is one run of 8-128 contiguous elements per
+# pixel, thousands of runs per slot.  :func:`coalesce_windows` cuts such a
+# slot into windows of the largest length ``l`` dividing every run of every
+# occurrence, and the executor assembles it from a table of window starts:
+# the same elements from the same rows, one index per window.  Measured on
+# a v5e (a 404,992-element channel half, 12,656 windows of 32): the element
+# gather costs ~18 ns an element; XLA:TPU turns a gather of ``(1, l)``
+# slices with ``l >= 8`` into a loop at ~1.6 us a window, so the executor
+# gathers the two ``LANES``-wide rows holding each window and shifts it
+# into place, ~17 ns a window whatever ``l``, plus a copy of the slab it
+# reads into row tiling.  Windows of 2 or more would pay by that count;
+# below 8 a slot is a fine interleave (lenet5's runs of 1-4) whose few
+# elements are not worth a 256-lane read per window and a slab copy, so it
+# keeps the element gather.
+MIN_WINDOW = 8
+# a window is read from the two LANES-wide rows that hold it, so it is at
+# most LANES long: a longer ``l`` is cut to its largest divisor that fits
+LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -571,4 +595,51 @@ def coalesce_spans(
             if rems else np.zeros((n_occ, 0), np.int32)
         ),
         coverage=float(coverage),
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowTable:
+    """Window decomposition of one signature slot's gather rows.
+
+    Every occurrence's row is ``L // length`` contiguous windows of
+    ``length`` elements; ``starts[o, k]`` is the first position of window
+    ``k`` (``rows[o, k * length]``).  ``sorted_`` holds when every
+    occurrence's starts ascend, so the gather may promise sorted indices."""
+
+    length: int
+    starts: np.ndarray   # (n_occ, L // length) int32 window start positions
+    sorted_: bool
+
+
+def window_length(rows: np.ndarray) -> int:
+    """Largest length dividing every maximal contiguous run of every row.
+
+    Runs end where an occurrence's next position is not its last plus one;
+    the gcd of the run lengths is the gcd of those break positions and
+    ``L``, taken over the union of every occurrence's breaks."""
+    L = rows.shape[1]
+    brk = (np.diff(rows.astype(np.int64), axis=1) != 1).any(axis=0)
+    return int(np.gcd.reduce(np.concatenate(([L], np.nonzero(brk)[0] + 1))))
+
+
+def coalesce_windows(
+    rows: np.ndarray, min_window: int = MIN_WINDOW
+) -> Optional[WindowTable]:
+    """Cut resolved gather rows ``(n_occ, L)`` into equal contiguous windows.
+
+    The window length is :func:`window_length`'s, cut to its largest
+    divisor of at most ``LANES``.  Returns ``None`` — keep the element
+    gather — when that length is below ``min_window``."""
+    if rows.shape[0] == 0 or rows.shape[1] == 0:
+        return None
+    gcd = window_length(rows)
+    ln = max(d for d in range(1, min(gcd, LANES) + 1) if gcd % d == 0)
+    if ln < min_window:
+        return None
+    starts = rows[:, ::ln].astype(np.int32)
+    return WindowTable(
+        length=ln,
+        starts=starts,
+        sorted_=bool((np.diff(starts.astype(np.int64), axis=1) >= 0).all()),
     )

@@ -740,6 +740,163 @@ class TestSpanCoalescing:
 
 
 # --------------------------------------------------------------------------- #
+# window gathers: scattered slots assembled as equal contiguous windows
+# --------------------------------------------------------------------------- #
+def _run_lengths(rows: np.ndarray):
+    """Every maximal contiguous run length of every occurrence, by a plain
+    walk over the positions."""
+    out = []
+    for row in rows.astype(np.int64):
+        n = 1
+        for a, b in zip(row[:-1], row[1:]):
+            if b == a + 1:
+                n += 1
+            else:
+                out.append(n)
+                n = 1
+        out.append(n)
+    return out
+
+
+class TestWindowGather:
+    """Wherever ``coalesce_windows`` elects the window gather, expanding its
+    window starts must give back the resolved gather rows bit for bit, so
+    the executor's ``(batch, length)`` gather reads the same elements as
+    the element gather.  Cases are ``TestSpanCoalescing``'s."""
+
+    CASES = [c[0] for c in TestSpanCoalescing.CASES]
+
+    @staticmethod
+    def _expand(win, rows):
+        from repro.codegen.segment import WindowTable
+        assert isinstance(win, WindowTable)
+        assert rows.shape[1] % win.length == 0
+        assert win.starts.shape == (rows.shape[0], rows.shape[1] // win.length)
+        return (
+            win.starts[:, :, None] + np.arange(win.length, dtype=np.int32)
+        ).reshape(rows.shape)
+
+    @given(st.sampled_from(CASES), st.integers(min_value=1, max_value=16))
+    @settings(max_examples=15, deadline=None)
+    def test_window_expansion_bit_identical(self, case, min_window):
+        from repro.codegen.segment import coalesce_windows
+        elected = 0
+        for rows in TestSpanCoalescing._rows(case):
+            win = coalesce_windows(rows, min_window=min_window)
+            if win is None:
+                continue
+            elected += 1
+            assert win.length >= min_window
+            assert (self._expand(win, rows) == rows).all()
+            ascending = (np.diff(win.starts.astype(np.int64), axis=1) >= 0)
+            assert win.sorted_ == bool(ascending.all())
+        if min_window == 1:
+            # every non-empty slot splits into windows of at least 1
+            assert elected == sum(
+                r.shape[1] > 0 for r in TestSpanCoalescing._rows(case))
+
+    @given(st.integers(min_value=0, max_value=2**31 - 1),
+           st.sampled_from((1, 2, 3, 8, 16, 24)))
+    @settings(max_examples=25, deadline=None)
+    def test_window_expansion_bit_identical_on_synthetic_runs(self, seed, ln):
+        """Rows built from runs whose lengths are multiples of ``ln``, with
+        gaps between runs and occurrences that share the run lengths."""
+        from repro.codegen.segment import coalesce_windows, window_length
+        rng = np.random.default_rng(seed)
+        lens = ln * rng.integers(1, 6, size=rng.integers(1, 10))
+        occs = []
+        for _ in range(int(rng.integers(1, 4))):
+            pos, row = int(rng.integers(0, 50)), []
+            for n in lens:
+                row.extend(range(pos, pos + int(n)))
+                pos += int(n) + int(rng.integers(1, 40))
+            occs.append(row)
+        rows = np.asarray(occs, np.int32)
+        assert window_length(rows) % ln == 0
+        win = coalesce_windows(rows, min_window=1)
+        assert (self._expand(win, rows) == rows).all()
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_window_length_is_gcd_of_run_lengths(self, case):
+        from repro.codegen.segment import window_length
+        for rows in TestSpanCoalescing._rows(case):
+            if rows.shape[1] == 0:
+                continue
+            assert window_length(rows) == int(
+                np.gcd.reduce(_run_lengths(rows))), case
+
+    def test_window_length_across_occurrences(self):
+        """One occurrence's runs of 8 and another's of 12 share windows of
+        4: the length divides every run of every occurrence."""
+        from repro.codegen.segment import window_length
+        a = np.r_[np.arange(0, 8), np.arange(100, 116)]
+        b = np.r_[np.arange(40, 52), np.arange(200, 212)]
+        rows = np.stack([a, b]).astype(np.int32)
+        assert sorted(set(_run_lengths(rows))) == [8, 12, 16]
+        assert window_length(rows) == 4
+
+    @pytest.mark.parametrize("length", (8, 24, 96, 128))
+    def test_window_gather_reads_every_offset(self, length):
+        """``_gather_windows`` over the slabs ``_window_slabs`` lays out
+        reads ``buf[:, s:s + length]`` for every start: every offset in a
+        row, windows that straddle two rows, and windows that end at the
+        carry's last written column (the slab's base pulled back)."""
+        from repro.codegen.executor import _gather_windows, _window_slabs
+        from repro.codegen.segment import LANES
+
+        width = 5 * LANES + 37                  # the carry before rounding
+        lane_width = -(-width // LANES) * LANES + LANES
+        rng = np.random.default_rng(length)
+        buf = rng.standard_normal((2, lane_width)).astype(np.float32)
+        starts = np.stack([
+            np.arange(0, LANES) + LANES,                      # every offset
+            np.sort(rng.integers(0, width - length, LANES)),  # anywhere
+            width - length - np.arange(LANES)[::-1] % 7,      # at the end
+        ]).astype(np.int32)
+        base, rel, slab = _window_slabs(starts, length, lane_width)
+        assert slab % LANES == 0 and (base % LANES == 0).all()
+        assert (base >= 0).all() and (base + slab <= lane_width).all()
+        gather = jax.jit(
+            lambda b, o, r: _gather_windows(b, o, r, length, slab, False))
+        for o in range(len(starts)):
+            got = np.asarray(gather(buf, base[o], rel[o]))
+            want = np.concatenate(
+                [buf[:, s:s + length] for s in starts[o]], axis=1)
+            assert (got == want).all(), o
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_short_windows_keep_element_gather(self, case):
+        from repro.codegen.segment import (
+            LANES,
+            MIN_WINDOW,
+            coalesce_windows,
+            window_length,
+        )
+        for rows in TestSpanCoalescing._rows(case):
+            if rows.shape[1] == 0:
+                assert coalesce_windows(rows) is None
+                continue
+            ln = window_length(rows)
+            fits = max(d for d in range(1, min(ln, LANES) + 1) if ln % d == 0)
+            win = coalesce_windows(rows)
+            if fits < MIN_WINDOW:
+                assert win is None, (case, ln)
+            else:
+                assert win is not None and win.length == fits, (case, ln)
+        runs_of_4 = (np.arange(0, 64, 8)[:, None] + np.arange(4)).reshape(1, -1)
+        assert window_length(runs_of_4) == 4
+        assert coalesce_windows(runs_of_4.astype(np.int32)) is None
+        # runs of 2 * 131 elements: no divisor between 2 and LANES
+        runs_262 = (np.arange(0, 2000, 500)[:, None] + np.arange(262))
+        assert window_length(runs_262.reshape(1, -1)) == 262
+        assert coalesce_windows(runs_262.reshape(1, -1).astype(np.int32)) is None
+        # runs of 384: windows of 128, three a run
+        runs_384 = (np.arange(0, 2000, 500)[:, None] + np.arange(384))
+        win = coalesce_windows(runs_384.reshape(1, -1).astype(np.int32))
+        assert win.length == LANES and win.starts.shape == (1, 12)
+
+
+# --------------------------------------------------------------------------- #
 # satellite: runtime knobs are bit-identical ablations
 # --------------------------------------------------------------------------- #
 class TestKnobBitIdentity:
