@@ -37,9 +37,16 @@ near layer-granularity cost while the unrolled one grows with task count.
 ``--profile`` builds the segmented executor and prints each segment's
 static statistics (ticks, signatures, ring rounds, comm patterns, span
 and window-gather coverage, window elements and their gather indices)
-beside the whole call's warm best-of-3 wall time.  The device
-time of each phase (assembly, kernels, comm, ...) is read from a profiler
-trace of the executor, whose ops carry named scopes (``codegen/executor.py``).
+beside the whole call's warm best-of-3 wall time; with ``--grid`` it
+first prints the slice search's counters (schedules, memo hits, layers,
+sliced layers, tasks), as its ``/repro/plan/slice_search`` event reports
+them.  The device time of each phase (assembly, kernels, comm, ...) is
+read from a profiler trace of the executor, whose ops carry named scopes
+(``codegen/executor.py``).  The full GoogLeNet's m=1 plan as the chip
+benchmark builds it:
+
+    PYTHONPATH=src python examples/schedule_sliced.py \
+        --model googlenet --input 224 --hw tpu --grid --workers 1 --profile
 
 ``--stream`` sweeps the segmented executor's ``buffer_depth`` knob
 (1 = write-once staging, 2/4 = rotating double/quad-buffered staging
@@ -53,7 +60,8 @@ on the chosen plan: the happens-before hazard verdict at buffer depths
 quantified removable-sync findings or the asserted minimality verdict).
 
     PYTHONPATH=src python examples/schedule_sliced.py \
-        [--model inception|lenet5|transformer] [--input 64] [--workers 8]
+        [--model inception|googlenet|lenet5|transformer] [--input 64]
+        [--workers 8]
         [--factor 8] [--spatial] [--auto-factors | --grid] [--hw keystone|tpu]
         [--tighten-s 0] [--segmented] [--profile] [--stream] [--analyze]
 """
@@ -72,6 +80,7 @@ from repro.codegen import build_mpmd_executor, build_plan, interpret_plan, plan_
 from repro.core import dsh, ish, speedup, tighten_schedule, validate
 from repro.core.costmodel import KEYSTONE_CPU, TPU_V5E
 from repro.models.cnn import (
+    googlenet,
     inception_net,
     lenet5,
     run_sequential,
@@ -130,7 +139,8 @@ def grid_report(model, hw, time_unit, workers, factors):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", choices=("inception", "lenet5", "transformer"),
+    ap.add_argument("--model",
+                    choices=("inception", "googlenet", "lenet5", "transformer"),
                     default="inception")
     ap.add_argument("--input", type=int, default=64,
                     help="input resolution of the CNN models")
@@ -162,7 +172,7 @@ def main():
     ap.add_argument("--profile", action="store_true",
                     help="per-segment static span/round statistics of the "
                          "segmented executor and its warm best-of-3 call "
-                         "time")
+                         "time; with --grid, the slice search's counters")
     ap.add_argument("--stream", action="store_true",
                     help="buffer_depth sweep {1,2,4} of the segmented "
                          "executor: per-depth carry width, staging "
@@ -181,6 +191,7 @@ def main():
 
     model = {
         "inception": lambda: inception_net(args.input),
+        "googlenet": lambda: googlenet(args.input),
         "lenet5": lambda: lenet5(28),
         "transformer": lambda: transformer_block(64, 128, 8, 256),
     }[args.model]()
@@ -188,8 +199,18 @@ def main():
     time_unit = 1e-6 if args.hw == "keystone" else 1e-9
 
     if args.grid:
+        search = {}
+
+        def listen(event, seconds, **attrs):
+            if event == "/repro/plan/slice_search":
+                search.update(seconds=round(seconds, 3), **attrs)
+
+        jax.monitoring.register_event_duration_secs_listener(listen)
         factors = search_slice_factors(model, hw, m=args.workers,
                                        time_unit=time_unit)
+        jax.monitoring.unregister_event_duration_listener(listen)
+        if args.profile:
+            print(f"slice search: {search}")
         grid_report(model, hw, time_unit, args.workers, factors)
     elif args.auto_factors:
         factors = choose_slice_factors(model, hw,

@@ -18,10 +18,11 @@ import jax
 @contextlib.contextmanager
 def timed(event: str, **attrs):
     """Report the wall seconds of the ``with`` body (or of each call, as a
-    decorator) as the duration event ``event``.  Nothing is reported when
-    the body raises."""
+    decorator) as the duration event ``event``.  ``with timed(...) as a``
+    gives the event's attributes, to which the body may add counts.
+    Nothing is reported when the body raises."""
     t0 = time.perf_counter()
-    yield
+    yield attrs
     jax.monitoring.record_event_duration_secs(
         event, time.perf_counter() - t0, **attrs
     )

@@ -7,7 +7,9 @@ two evaluation networks:
 * **LeNet-5** (Fig. 1) and its *branchified* variant (Fig. 2: the first
   conv/pool stage split into two parallel branches);
 * the **GoogLeNet-like** net of Fig. 10 (conv/pool stem + two inception
-  modules with 4 parallel branches each + avgpool/gemm head).
+  modules with 4 parallel branches each + avgpool/gemm head);
+
+and the full **GoogLeNet** it is cut from (Szegedy et al. 2015, Table 1).
 
 Each :class:`LayerSpec` is a pure op over its parents' outputs; layer WCETs
 ``t(v)`` and edge transfer costs ``w(e)`` come from the roofline cost model,
@@ -44,6 +46,7 @@ __all__ = [
     "lenet5",
     "lenet5_branchy",
     "inception_net",
+    "googlenet",
     "transformer_block",
     "apply_layer",
     "run_sequential",
@@ -538,6 +541,63 @@ def inception_net(input_hw: int = 224, n_classes: int = 10) -> CNNModel:
     ls.append(_dense("gemm", "reshape", c, n_classes, relu=False))
     ls.append(LayerSpec("output", "output", ("gemm",), (n_classes,)))
     return CNNModel("inception", tuple(ls))
+
+
+# Table 1 of arXiv:1409.4842, per inception module: #1x1, #3x3 reduce, #3x3,
+# #5x5 reduce, #5x5, pool proj; a stride-2 max pool follows stages 3 and 4
+GOOGLENET_STAGES: Tuple[Tuple[Tuple[str, Tuple[int, ...]], ...], ...] = (
+    (("3a", (64, 96, 128, 16, 32, 32)),
+     ("3b", (128, 128, 192, 32, 96, 64))),
+    (("4a", (192, 96, 208, 16, 48, 64)),
+     ("4b", (160, 112, 224, 24, 64, 64)),
+     ("4c", (128, 128, 256, 24, 64, 64)),
+     ("4d", (112, 144, 288, 32, 64, 64)),
+     ("4e", (256, 160, 320, 32, 128, 128))),
+    (("5a", (256, 160, 320, 32, 128, 128)),
+     ("5b", (384, 192, 384, 48, 128, 128))),
+)
+
+
+def googlenet(input_hw: int = 224, n_classes: int = 1000) -> CNNModel:
+    """GoogLeNet (Szegedy et al. 2015, arXiv:1409.4842, Table 1), the
+    inference path at its published widths: the 7x7/2 stem, the 1x1
+    ``conv_2_reduce`` and 3x3 ``conv_2``, nine inception modules in three
+    stages with a 3x3/2 max pool after the stem and after stages 3 and 4,
+    a global average pool and a dense head with no ReLU.
+
+    Departures from the paper: no local response normalisation (as in
+    torchvision's GoogLeNet); no softmax, so the head returns logits;
+    dropout is the identity at inference; the two auxiliary classifiers
+    are training-only and not built. Every conv and pool pads SAME, as
+    TensorFlow's Inception v1 does. For the 3x3/2 pools at 112, 56, 28 and
+    14 that reads the same windows as Caffe's ceil-mode pools; Caffe's
+    7x7/2 conv pads 3 rows and columns before, SAME pads 2 before and 3
+    after.
+    """
+    s = input_hw
+    ls: List[LayerSpec] = [LayerSpec("input", "input", (), (s, s, 3))]
+    ls.append(_conv("conv_1", "input", (s, s, 3), 64, 7, stride=2))
+    s = s // 2
+    ls.append(_pool("maxpool_1", "maxpool", "conv_1", (s, s, 64), kernel=3, stride=2))
+    s = (s + 1) // 2
+    ls.append(_conv("conv_2_reduce", "maxpool_1", (s, s, 64), 64, 1))
+    ls.append(_conv("conv_2", "conv_2_reduce", (s, s, 64), 192, 3))
+    ls.append(_pool("maxpool_2", "maxpool", "conv_2", (s, s, 192), kernel=3, stride=2))
+    parent, shape = "maxpool_2", ((s + 1) // 2, (s + 1) // 2, 192)
+    for stage, modules in enumerate(GOOGLENET_STAGES, start=3):
+        if stage > 3:
+            name = f"maxpool_{stage - 1}"
+            ls.append(_pool(name, "maxpool", parent, shape, kernel=3, stride=2))
+            parent, shape = name, ls[-1].out_shape
+        for tag, widths in modules:
+            shape = _inception(ls, f"inception_{tag}", parent, shape, *widths)
+            parent = f"inception_{tag}/concat"
+    h, w, c = shape
+    ls.append(_pool("avgpool", "avgpool", parent, shape, kernel=h, stride=h))
+    ls.append(LayerSpec("flatten", "reshape", ("avgpool",), (c,)))
+    ls.append(_dense("fc", "flatten", c, n_classes, relu=False))
+    ls.append(LayerSpec("output", "output", ("fc",), (n_classes,)))
+    return CNNModel("googlenet", tuple(ls))
 
 
 def transformer_block(

@@ -735,7 +735,6 @@ GRID_CANDIDATES: Tuple[Optional[Factor], ...] = (
 )
 
 
-@timed("/repro/plan/slice_search")
 def search_slice_factors(
     model: CNNModel,
     hw: HardwareSpec = TPU_V5E,
@@ -764,33 +763,34 @@ def search_slice_factors(
     TPU-priced inception (224) with 8 workers the result schedules >= 10%
     below the best uniform single-axis tiling (asserted in the benchmark's
     grid acceptance gate).
+
+    Reports the duration event ``/repro/plan/slice_search`` with the counts
+    ``schedules`` (distinct factor maps sliced and scheduled),
+    ``memo_hits`` (maps priced again from the memo), and of the result
+    ``layers`` (the model's), ``sliced_layers`` (layers given a factor) and
+    ``tasks`` (layers of the sliced model).
     """
     if heuristic is None:
         from repro.core.list_scheduling import dsh as heuristic  # noqa: PLC0415
 
-    memo: Dict[frozenset, float] = {}
+    # factor map -> (makespan, sliced task count), memoized across rounds:
+    # the convergence round re-visits every candidate it already scheduled,
+    # so it becomes pure lookups
+    memo: Dict[frozenset, Tuple[float, int]] = {}
+    memo_hits = 0
 
     def evaluate(factors: Mapping[str, Factor]) -> float:
-        # memoized across rounds: the convergence round re-visits every
-        # candidate it already scheduled, so it becomes pure lookups
+        nonlocal memo_hits
         key = frozenset(factors.items())
-        mk = memo.get(key)
-        if mk is None:
+        got = memo.get(key)
+        if got is None:
             sliced = slice_model(model, factors)
             sdag = sliced.to_dag(hw, time_unit=time_unit)
-            mk = memo[key] = heuristic(sdag, m).makespan(sdag)
-        return mk
+            got = memo[key] = (heuristic(sdag, m).makespan(sdag), len(sliced.layers))
+        else:
+            memo_hits += 1
+        return got[0]
 
-    best_mk, best = min(
-        (
-            (evaluate(f), f)
-            for n in seeds
-            for f in (uniform_factors(model, n),
-                      uniform_factors(model, n, spatial=True))
-        ),
-        key=lambda kv: kv[0],
-    )
-    cur = dict(best)
     opset = frozenset(SLICEABLE_OPS)
     order = sorted(
         (l for l in model.layers if l.op in opset),
@@ -809,34 +809,49 @@ def search_slice_factors(
         n = c if isinstance(c, int) else int(c[0]) * int(c[1])
         return None if n < 2 else n
 
-    for _ in range(max(1, rounds)):
-        improved = False
-        for l in order:
-            base = cur.get(l.name)
-            best_c, best_v = base, best_mk
-            seen = {norm(l, base)}
-            for c in candidates:
-                key = norm(l, c)
-                if key in seen:
-                    continue
-                seen.add(key)
-                trial = dict(cur)
-                if c is None:
-                    trial.pop(l.name, None)
-                else:
-                    trial[l.name] = c
-                v = evaluate(trial)
-                if v < best_v - 1e-9:
-                    best_v, best_c = v, c
-            if best_c != base:
-                if best_c is None:
-                    cur.pop(l.name, None)
-                else:
-                    cur[l.name] = best_c
-                best_mk = best_v
-                improved = True
-        if not improved:
-            break
+    with timed("/repro/plan/slice_search") as counts:
+        best_mk, best = min(
+            (
+                (evaluate(f), f)
+                for n in seeds
+                for f in (uniform_factors(model, n),
+                          uniform_factors(model, n, spatial=True))
+            ),
+            key=lambda kv: kv[0],
+        )
+        cur = dict(best)
+        for _ in range(max(1, rounds)):
+            improved = False
+            for l in order:
+                base = cur.get(l.name)
+                best_c, best_v = base, best_mk
+                seen = {norm(l, base)}
+                for c in candidates:
+                    key = norm(l, c)
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                    trial = dict(cur)
+                    if c is None:
+                        trial.pop(l.name, None)
+                    else:
+                        trial[l.name] = c
+                    v = evaluate(trial)
+                    if v < best_v - 1e-9:
+                        best_v, best_c = v, c
+                if best_c != base:
+                    if best_c is None:
+                        cur.pop(l.name, None)
+                    else:
+                        cur[l.name] = best_c
+                    best_mk = best_v
+                    improved = True
+            if not improved:
+                break
+        counts.update(
+            schedules=len(memo), memo_hits=memo_hits, layers=len(model.layers),
+            sliced_layers=len(cur), tasks=memo[frozenset(cur.items())][1],
+        )
     return cur
 
 
