@@ -36,8 +36,8 @@ near layer-granularity cost while the unrolled one grows with task count.
 
 ``--profile`` builds the segmented executor and prints each segment's
 static statistics (ticks, signatures, ring rounds, comm patterns, span
-and window-gather coverage, window elements and their gather indices)
-beside the whole call's warm best-of-3 wall time; with ``--grid`` it
+and window-gather coverage, window elements and their gather indices,
+landing scans, columns landed per request and their efficiency) beside the whole call's warm best-of-3 wall time; with ``--grid`` it
 first prints the slice search's counters (schedules, memo hits, layers,
 sliced layers, tasks), as its ``/repro/plan/slice_search`` event reports
 them.  The device time of each phase (assembly, kernels, comm, ...) is
@@ -362,7 +362,8 @@ def profile_segments(plan, sliced, params, mesh, x, ref):
 
     Prints each segment's ticks, signatures, ring rounds, comm patterns,
     span and window-gather coverage, window elements and window indices,
-    and the whole executor's warm best-of-3 call time.
+    landing scans, columns landed per request and their efficiency, and
+    the whole executor's warm best-of-3 call time.
     Device time per phase comes from a profiler trace of the call: the
     executor's ops carry ``seg<k>/<phase>`` named scopes."""
     batch = x.shape[0]
@@ -373,13 +374,15 @@ def profile_segments(plan, sliced, params, mesh, x, ref):
           f"warm call {_best_ms(f, x):.2f} ms")
     print(f"{'seg':>4} {'steps':>9} {'ticks':>5} {'sigs':>4} {'rnds':>4} "
           f"{'pats':>4} {'cov':>5} {'win':>5} {'win_elems':>10} "
-          f"{'win_idx':>8}")
+          f"{'win_idx':>8} {'land':>4} {'land_elems':>11} {'l_eff':>5}")
     for k, st in enumerate(f.segment_stats):
         lo, hi = st["steps"]
         print(f"{k:>4} {f'{lo}-{hi}':>9} {st['ticks']:>5} {st['sigs']:>4} "
               f"{st['rounds']:>4} {st['comm_patterns']:>4} "
               f"{st['span_coverage']:>5.2f} {st['window_coverage']:>5.2f} "
-              f"{st['window_elems']:>10} {st['window_indices']:>8}")
+              f"{st['window_elems']:>10} {st['window_indices']:>8} "
+              f"{st['land_runs']:>4} {st['land_elems']:>11} "
+              f"{st['land_efficiency']:>5.3f}")
 
 
 def stream_report(plan, sliced, params, mesh, x, ref):

@@ -1157,7 +1157,7 @@ def segment_access_tables(
 
 def _make_branch(
     sig, tab, x, batch: int, gin_kinds, pidx_identity: bool,
-    const_pops=None, wseg: int = 1,
+    const_pops=None, wland: int = 1,
 ):
     """One switch branch: assemble the signature's input blocks from the
     packed buffer through the occurrence's index tables, run the shared
@@ -1168,10 +1168,11 @@ def _make_branch(
     that each copy the buffer (ruinously expensive on a wide carry), so
     every branch instead returns a small ``(y_pad, start)`` pair and the
     caller performs one in-place ``dynamic_update_slice`` outside the
-    switch.  ``y_pad`` is the kernel output padded to the segment-wide
-    width ``wseg`` with a *self-restoring tail* — the current buffer
-    contents at ``[start + w, start + wseg)`` — so the uniform-width
-    write never corrupts neighbouring columns.
+    switch.  ``y_pad`` is the kernel output padded to the landing width
+    ``wland`` of the branch's scan (the widest register it lands, see
+    ``segment.land_runs``) with a *self-restoring tail* — the current
+    buffer contents at ``[start + w, start + wland)`` — so the
+    uniform-width write never corrupts neighbouring columns.
 
     Per-slot assembly is span-coalesced (``gin_kinds[j] == ("spans", lens,
     kinds)``): each contiguous piece of the slot's gather rows is one
@@ -1253,13 +1254,13 @@ def _make_branch(
             y2 = jax.lax.reshape(y, (batch, w))
         with jax.named_scope("land"):
             st = _take_row(tab["out"], oc)
-            if w < wseg:
+            if w < wland:
                 # self-restoring tail: read back what the uniform-width
                 # write is about to overwrite, so the pad columns keep
                 # their values
                 tail = jax.lax.dynamic_slice_p.bind(
                     buf, np.int32(0), jax.lax.add(st, np.int32(w)),
-                    slice_sizes=(batch, wseg - w),
+                    slice_sizes=(batch, wland - w),
                 )
                 y2 = jax.lax.concatenate([y2, tail], 1)
         return y2, st
@@ -1288,7 +1289,8 @@ def _build_segmented(
     schema; this builder adds the model-side compute tables — per-segment
     kernel lists keyed by structural signature, with per-occurrence operand
     tables (register offsets, span starts, deduplicated parameter slices) —
-    and emits one scan per segment.  All tables are passed as jit arguments
+    and emits one scan per landing run of each segment
+    (``segment.land_runs``).  All tables are passed as jit arguments
     rather than baked as constants, so tracing cost stays bounded by the
     number of distinct signatures.
 
@@ -1313,9 +1315,11 @@ def _build_segmented(
     """
     from repro.codegen.segment import (
         LANES,
+        LAND_SCAN_COST,
         SpanTable,
         coalesce_spans,
         coalesce_windows,
+        land_runs,
         node_signature,
         param_slices,
         resolve_rows,
@@ -1372,7 +1376,7 @@ def _build_segmented(
             pat_ids[t] = pid
         seg_patterns.append(tuple(patterns))
         seg_patids.append(pat_ids)
-    # the uniform-width output write needs `start + wseg <= width` for
+    # the uniform-width output write needs `start + wland <= width` for
     # every output offset (starts never exceed `total`); the staging
     # extent already covers every tick block plus its read-back tail
     wmax = max(
@@ -1396,8 +1400,9 @@ def _build_segmented(
             sig_cache[node] = node_signature(model, node)
         return sig_cache[node]
 
-    seg_meta = []     # (sig_list, sig_infos, deltas, lengths, single,
-                      #  patterns, lmax, wseg, idle_st, has_ret)
+    seg_meta = []     # (sig_list, sig_infos, deltas, lengths, patterns,
+                      #  lmax, has_ret, runs); runs: (start, stop, sids,
+                      #  single, wland, idle_st) per landing run
     seg_tables = []   # per segment: pytree of jnp operand tables (jit args)
     seg_stats = []    # per segment: static span/round statistics
     for seg_i, seg in enumerate(segments):
@@ -1514,22 +1519,12 @@ def _build_segmented(
                     )
             sig_tabs.append(tab)
             sig_infos.append((tuple(gin_kinds), pidx_identity, const_pops))
-        # single-structure specialization: one signature and no idle cells
-        # means every tick runs the same branch — skip the lax.switch and
-        # its operand plumbing entirely
-        single = len(sig_list) == 1 and bool((sig_tab != 0).all())
         lmax = max(
             [0] + [
                 sum(seg.rounds[r].length for r in pat) for pat in patterns
             ]
         )
-        wseg = max(
-            [1] + [reg_sizes[n] for row in seg.ticks for n in row if n]
-        )
-        idle_st = width - wseg
         xs = {"occ": jnp.asarray(occ_tab)}
-        if not single:
-            xs["sig"] = jnp.asarray(sig_tab)
         if seg.rounds:
             xs["slot"] = jnp.asarray(
                 np.stack([r.slot for r in seg.rounds], axis=1)
@@ -1552,10 +1547,36 @@ def _build_segmented(
         mat = None
         if acc.mat is not None:
             mat = (jnp.asarray(acc.mat[0]), jnp.asarray(acc.mat[1]))
+        # landing runs: consecutive scans over the segment's tick rows,
+        # each landing at its own widest tick (the widest register any
+        # worker lands there), so every worker cuts at the same ticks and
+        # the comm rounds' collectives stay matched
+        tick_w = [max([0] + [reg_sizes[n] for n in row if n])
+                  for row in seg.ticks]
+        runs = []
+        land_elems = 0
+        sig_run = np.zeros_like(sig_tab)  # each cell's branch in its run
+        for a, b in land_runs(tick_w, LAND_SCAN_COST):
+            # the run's switch holds only the signatures it runs, in the
+            # segment's order (so one run over the segment is the
+            # segment's switch)
+            sids = sorted({int(s) - 1 for s in sig_tab[a:b].ravel() if s})
+            remap = np.zeros(len(sig_list) + 1, np.int32)
+            remap[[s + 1 for s in sids]] = np.arange(1, len(sids) + 1)
+            sig_run[a:b] = remap[sig_tab[a:b]]
+            # single-structure specialization: one signature and no idle
+            # cells means every tick runs the same branch — skip the
+            # lax.switch and its operand plumbing entirely
+            single = len(sids) == 1 and bool((sig_tab[a:b] != 0).all())
+            wland = max([1] + tick_w[a:b])
+            land_elems += (b - a) * wland
+            runs.append((a, b, tuple(sids), single, wland, width - wland))
+        if not all(r[3] for r in runs):
+            xs["sig"] = jnp.asarray(sig_run)
         seg_meta.append((
             sig_list, sig_infos, tuple(r.delta for r in seg.rounds),
-            tuple(r.length for r in seg.rounds), single, patterns,
-            lmax, wseg, idle_st, bool(ret_k),
+            tuple(r.length for r in seg.rounds), patterns,
+            lmax, bool(ret_k), tuple(runs),
         ))
         seg_tables.append({
             "xs": xs,
@@ -1599,6 +1620,12 @@ def _build_segmented(
             "window_coverage": (
                 window_elems / gather_elems if gather_elems else 0.0
             ),
+            # landing writes: scans, columns landed per request (each
+            # tick at its run's width), and the ticks' own widest outputs
+            # over that
+            "land_runs": len(runs),
+            "land_elems": land_elems,
+            "land_efficiency": sum(tick_w) / land_elems,
         })
 
     if windowed:
@@ -1608,7 +1635,8 @@ def _build_segmented(
     sink_shape = reg_shapes[plan.sink]
 
     def run_segment(buf, x, meta, tabs):
-        """Scan one segment's ticks over the packed carry.
+        """Scan one segment's ticks over the packed carry, one scan per
+        landing run (``segment.land_runs``), in tick order.
 
         Every per-tick write is an in-place ``dynamic_update_slice``: the
         switch returns ``(y_pad, start)`` values (see ``_make_branch``)
@@ -1621,24 +1649,43 @@ def _build_segmented(
         Phases are named scopes: the branches' own (``_make_branch``),
         ``land`` for the idle branch and the tick's write, ``retire`` and
         ``comm``."""
+        for run in meta[-1]:
+            buf = scan_run(buf, x, meta, tabs, run)
+        return buf
+
+    def scan_run(buf, x, meta, tabs, run):
+        """One landing run's ticks as one ``lax.scan``: its switch holds
+        the run's signatures and every branch lands ``wland`` columns.
+
+        The run's rows of the segment's tick tables are sliced here, in
+        the program, and not passed apart: every table is an argument that
+        each call places on each worker's device, so the argument count
+        stays the segment's whatever the cut."""
         wid = jax.lax.axis_index(axis)
-        (sig_list, sig_infos, deltas, lengths, single, patterns,
-         lmax, wseg, idle_st, has_ret) = meta
+        (sig_list, sig_infos, deltas, lengths, patterns,
+         lmax, has_ret, _runs) = meta
+        a, b, sids, single, wland, idle_st = run
+        xs = {
+            k: v if (a, b) == (0, v.shape[0])
+            else jax.lax.slice_in_dim(v, a, b)
+            for k, v in tabs["xs"].items() if k != "sig" or not single
+        }
+        rows = tabs["rows"]
 
         def idle(b, oc):
-            # self-restoring no-op: read wseg columns, write them back
+            # self-restoring no-op: read wland columns, write them back
             with jax.named_scope("land"):
                 return (
-                    jax.lax.slice(b, (0, idle_st), (batch, idle_st + wseg)),
+                    jax.lax.slice(b, (0, idle_st), (batch, idle_st + wland)),
                     jnp.asarray(idle_st, jnp.int32),
                 )
 
         branches = [idle]
-        for sig, info, st in zip(sig_list, sig_infos, tabs["sigs"]):
+        for s in sids:
             branches.append(_make_branch(
-                sig, st, x, batch, *info, wseg=wseg,
+                sig_list[s], tabs["sigs"][s], x, batch, *sig_infos[s],
+                wland=wland,
             ))
-        rows = tabs["rows"]
 
         def body(b, tk):
             oc = _take_row(tk["occ"], wid)
@@ -1714,7 +1761,7 @@ def _build_segmented(
                 )
             return b, None
 
-        buf, _ = jax.lax.scan(body, buf, tabs["xs"])
+        buf, _ = jax.lax.scan(body, buf, xs)
         return buf
 
     def init_buf() -> jax.Array:

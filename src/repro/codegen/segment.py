@@ -53,6 +53,8 @@ __all__ = [
     "window_length",
     "coalesce_windows",
     "LANES",
+    "LAND_SCAN_COST",
+    "land_runs",
     "resolve_rows",
     "max_sentinel_runs",
 ]
@@ -643,3 +645,46 @@ def coalesce_windows(
         starts=starts,
         sorted_=bool((np.diff(starts.astype(np.int64), axis=1) >= 0).all()),
     )
+
+
+# Every tick lands its output with one in-place ``dynamic_update_slice`` of
+# a width shared by all the ticks of one scan, padded with a self-restoring
+# tail (``executor._make_branch``).  That write costs its width, not the
+# carry's: on a v5e, tail read, pad and write in a two-branch switch took
+# 78.6 us a tick at 802,816 columns and 6.2 us at 128, ~0.090 ns a column
+# over a fixed part.  So :func:`land_runs` cuts a segment's ticks into
+# consecutive scans, each landing its own widest register.  A cut pays one
+# more scan's entry and exit: 240 such ticks over a 5,602,176-column carry
+# took 151 us more as 24 scans than as one, 6.57 us a scan, the time of
+# ~73,000 columns of landing.  That makes lenet5's best cut (50,176 columns
+# a request, ~4.5 us) not worth a scan.
+LAND_SCAN_COST = 73_000
+
+
+def land_runs(
+    widths: List[int], scan_cost: int
+) -> Tuple[Tuple[int, int], ...]:
+    """Cut ticks ``0..n`` into contiguous runs ``[a, b)`` scanned apart.
+
+    A run lands ``b - a`` ticks at its widest tick's width (at least 1).
+    The cut minimises the columns landed, ``sum((b - a) * max(widths[a:b]))``,
+    plus ``scan_cost`` for each run after the first, by dynamic programming
+    over the run's last start; ties keep the longer run, so a segment whose
+    cuts save no more than they cost stays one run."""
+    n = len(widths)
+    best = [0] * (n + 1)
+    start = [0] * (n + 1)
+    for j in range(1, n + 1):
+        wr, cost_j = 1, None
+        for i in range(j - 1, -1, -1):
+            wr = max(wr, widths[i])
+            c = best[i] + (j - i) * wr + (scan_cost if i else 0)
+            if cost_j is None or c <= cost_j:
+                cost_j, start[j] = c, i
+        best[j] = cost_j
+    runs = []
+    j = n
+    while j:
+        runs.append((start[j], j))
+        j = start[j]
+    return tuple(runs[::-1])
