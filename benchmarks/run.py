@@ -19,13 +19,12 @@ def main(argv=None) -> int:
     failures = 0
     t00 = time.time()
 
-    from benchmarks import fig7_heuristics, fig8_cp, table1_wcet, table3_measured, roofline
+    from benchmarks import fig7_heuristics, fig8_cp, table1_wcet, roofline
 
     sections = [
         ("fig7 (ISH/DSH heuristics)", fig7_heuristics.main),
         ("fig8 (CP encodings)", fig8_cp.main),
         ("table1 (WCET schedule, paper's OTAWA bounds)", table1_wcet.main),
-        ("table3 (measured MPMD execution)", table3_measured.main),
         ("roofline (dry-run artifacts)", roofline.main),
     ]
     if quick:

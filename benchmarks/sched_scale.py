@@ -1,4 +1,4 @@
-"""Scheduler + executor scaling benchmark — the repo's perf baseline.
+"""Scheduler + executor scaling benchmark on the CPU.
 
 Times the fast-path pipeline across DAG sizes and worker counts:
 
@@ -44,30 +44,15 @@ Times the fast-path pipeline across DAG sizes and worker counts:
                           the trend gates (sliced lenet5 m=4 always — the CI
                           smoke; 1k-request grid-sliced inception(64) m=8 on
                           full runs)
-* ``trace``             — shard_map MPMD executor trace (lowering) time on
-                          the ``schedule_cnn`` example models **and sliced
-                          plans** (``trace_ms`` per sliced plan, unrolled
-                          and segmented executors side by side)
-* ``segmented gate``    — the segmented ``lax.scan`` executor must trace a
-                          grid-sliced inception plan within 5x of the
-                          layer-granularity plan's unrolled trace on 8
-                          workers (``SEGMENTED_TRACE_FACTOR``), so the
-                          trace win is gated like the makespan wins
-* ``run gate``          — segmented *runtime* parity on the same grid plan:
-                          warm-up + interleaved best-of-3 ``run_ms`` for
-                          both executors; fails unless segmented is within
-                          ``SEGMENTED_RUN_FACTOR`` (2x) of unrolled or
-                          under the ``SEGMENTED_RUN_FLOOR_MS`` absolute
-                          floor (the binding bar on 1-core CI hosts where
-                          fake devices serialize and ratios are noise)
-* ``stream gate``       — the ``buffer_depth`` sweep on the same grid plan
+* ``stream gate``       — the ``buffer_depth`` sweep on the grid-sliced
+                          inception(64) m=8 plan
                           (``benchmarks/stream_overlap.py``): per-depth
                           sustained supersteps/s through the serving
                           frontend and the resident staging footprint;
                           depth >= 2 must sustain
                           ``STREAM_SPEEDUP`` (1.2x) over depth 1 or beat
                           the ``STREAM_FLOOR_STEPS_S`` absolute floor (the
-                          1-core CI escape, like the run gate), and
+                          1-core CI escape), and
                           ``peak_staging_bytes`` is deterministic so the
                           ``kind="stream"`` rows join the byte trend gate
 * reference equivalence — on sizes where the original O(V²·E) driver is
@@ -90,8 +75,8 @@ scheduled transfer bytes grow more than 1.5x over the committed baseline
 """
 import os
 
-# must be set before jax initializes — the executor-trace section meshes
-# over fake host devices
+# must be set before jax initializes — the stream section meshes over fake
+# host devices
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
@@ -119,35 +104,6 @@ GRID_VS_1D_BUDGET = 0.9     # acceptance: the searched 2-D grid tiling must
                             # schedule >= 10% below the best uniform 1-D
                             # tiling on TPU-priced inception(224), 8 workers
                             # (deterministic scheduling -> no slack needed)
-SEGMENTED_TRACE_FACTOR = 5.0  # acceptance: the segmented lax.scan executor
-                              # must trace a grid-sliced inception plan
-                              # within 5x of the layer-granularity plan's
-                              # (unrolled) trace on 8 workers (best-of-3
-                              # timings to damp machine noise).  Was 2x
-                              # when the segmented path element-gathered
-                              # everything; the runtime fast paths (span
-                              # dynamic_slices, cohort pattern-switch comm)
-                              # buy an ~8x run-time win for a bounded
-                              # trace-time cost — measured ~2.9x standalone
-                              # and ~3.8x late in the full bench process,
-                              # still ~3x *faster* to trace than the
-                              # unrolled executor on the same plan
-SEGMENTED_RUN_FACTOR = 2.0    # acceptance: the segmented executor must *run*
-                              # grid-sliced inception m=8 within 2x of the
-                              # unrolled executor ...
-SEGMENTED_RUN_FLOOR_MS = 150.0  # ... OR under this absolute wall time.  The
-                                # ratio is only measurable on real multi-core
-                                # hosts: with 8 fake host devices sharing one
-                                # core the workers serialize, per-op dispatch
-                                # dominates, and both executors sit in a wide
-                                # noise band — best-of-3 measures 50ms in a
-                                # fresh process but up to ~80ms late in the
-                                # full bench run.  The floor sits ~2x above
-                                # the worst observed healthy reading and
-                                # ~2.5x below the ~400ms pre-optimization
-                                # runtime it guards against, so on 1-core CI
-                                # it is the binding regression bar without
-                                # flaking on process state.
 
 
 def bench_schedulers(sizes, workers, density, ref_max_nodes, results):
@@ -545,250 +501,6 @@ def check_trend(results, baseline_path):
     return checked
 
 
-def bench_executor_trace(workers, results):
-    import jax
-    from repro.core import dsh
-    from repro.core.costmodel import KEYSTONE_CPU
-    from repro.codegen import build_mpmd_executor
-    from repro.models.cnn import inception_net
-
-    model = inception_net(64)
-    dag = model.to_dag(KEYSTONE_CPU, time_unit=1e-6)
-    key = jax.random.PRNGKey(0)
-    params = model.init_params(key)
-    x = jax.numpy.zeros((1, 64, 64, 3))
-    n_dev = jax.device_count()
-    for m in workers:
-        if m > n_dev:
-            print(f"trace m={m}: skipped ({n_dev} devices available)")
-            continue
-        plan = build_plan(dsh(dag, m), dag)
-        mesh = jax.make_mesh((m,), ("workers",))
-        for fused in (True, False):
-            f = build_mpmd_executor(
-                plan, model, params, mesh, batch=1, fuse_transfers=fused
-            )
-            t0 = time.perf_counter()
-            f.lower(x)
-            dt = time.perf_counter() - t0
-            results.append({
-                "kind": "executor_trace",
-                "model": model.name,
-                "n_workers": m,
-                "fuse_transfers": fused,
-                "trace_s": round(dt, 4),
-                "supersteps": len(plan.steps),
-                "transfers": plan.n_transfers,
-            })
-            print(
-                f"trace {model.name} m={m} fused={int(fused)}: {dt:6.3f}s "
-                f"({plan.n_transfers} transfers)"
-            )
-
-
-def bench_sliced_trace(workers, results, slice_factor=4):
-    """MPMD-executor trace time on *sliced* plans (``trace_ms`` column) —
-    the evidence base for the ROADMAP's lax.scan/segmented-executor item:
-    the unrolled superstep loop makes trace time grow with slice count."""
-    import jax
-    from repro.core import dsh
-    from repro.core.costmodel import KEYSTONE_CPU
-    from repro.codegen import build_mpmd_executor, coalesce_transfer_steps
-    from repro.models.cnn import inception_net, lenet5
-    from repro.models.slicing import slice_model, uniform_factors
-
-    key = jax.random.PRNGKey(0)
-    n_dev = jax.device_count()
-    for model in (lenet5(28), inception_net(64)):
-        params = model.init_params(key)
-        x = jax.numpy.zeros((1, *model.layers[0].out_shape))
-        sliced = slice_model(model, uniform_factors(model, slice_factor))
-        sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
-        for m in workers:
-            if m > n_dev:
-                continue
-            plan = build_plan(dsh(sdag, m), sdag)
-            # the executor coalesces transfer-only rounds before lowering;
-            # report the coalesced plan's shape so trace_ms and the
-            # superstep count describe the same traced program
-            traced = coalesce_transfer_steps(plan)
-            mesh = jax.make_mesh((m,), ("workers",))
-            for segmented in (False, True):
-                f = build_mpmd_executor(
-                    plan, sliced, params, mesh, batch=1, segmented=segmented
-                )
-                t0 = time.perf_counter()
-                f.lower(x)
-                trace_ms = (time.perf_counter() - t0) * 1e3
-                results.append({
-                    "kind": "executor_trace",
-                    "model": sliced.name,
-                    "sliced": True,
-                    "segmented": segmented,
-                    "n_workers": m,
-                    "trace_ms": round(trace_ms, 1),
-                    "supersteps": len(traced.steps),
-                    "transfers": traced.n_transfers,
-                })
-                print(
-                    f"trace {sliced.name} m={m} seg={int(segmented)}: "
-                    f"{trace_ms:7.1f}ms ({len(traced.steps)} supersteps, "
-                    f"{traced.n_transfers} transfers)"
-                )
-
-
-def bench_segmented_trace_gate(results):
-    """Acceptance: the segmented lax.scan executor must trace a *grid-sliced*
-    inception plan (2-D (2 x 4) conv/pool tiles, ~165 tasks) within
-    ``SEGMENTED_TRACE_FACTOR`` (5x) of the layer-granularity plan's unrolled
-    trace on 8 workers — the ROADMAP "sliced executor traces" item, gated
-    like the makespan wins.  Best-of-3 lowerings per executor damp machine
-    noise; the first layer-granularity run also absorbs jax warmup."""
-    import gc
-
-    import jax
-    from repro.core import dsh
-    from repro.core.costmodel import KEYSTONE_CPU
-    from repro.codegen import build_mpmd_executor, coalesce_transfer_steps
-    from repro.models.cnn import inception_net
-    from repro.models.slicing import slice_model, uniform_factors
-
-    gc.collect()  # drop earlier benches' executors before timing lowerings
-    m = 8
-    if jax.device_count() < m:
-        print(f"segmented gate: skipped ({jax.device_count()} devices)")
-        return
-    model = inception_net(64)
-    key = jax.random.PRNGKey(0)
-    params = model.init_params(key)
-    x = jax.numpy.zeros((1, 64, 64, 3))
-    mesh = jax.make_mesh((m,), ("workers",))
-    dag = model.to_dag(KEYSTONE_CPU, time_unit=1e-6)
-    layer_plan = build_plan(dsh(dag, m), dag)
-    base = uniform_factors(model, 8, spatial=True)
-    factors = {k: ((2, 4) if v == (1, 8) else v) for k, v in base.items()}
-    sliced = slice_model(model, factors)
-    sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
-    grid_plan = build_plan(dsh(sdag, m), sdag)
-
-    def best_trace(plan_, mdl, **kw):
-        best = None
-        for _ in range(3):
-            f = build_mpmd_executor(plan_, mdl, params, mesh, batch=1, **kw)
-            t0 = time.perf_counter()
-            f.lower(x)
-            dt = time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        return best
-
-    layer_s = best_trace(layer_plan, model)
-    seg_s = best_trace(grid_plan, sliced, segmented=True)
-    unr_s = best_trace(grid_plan, sliced)
-    ratio = seg_s / layer_s
-    results.append({
-        "kind": "segmented_trace_gate",
-        "model": "inception@grid2x4",
-        "n_workers": m,
-        "n_nodes": len(sdag.nodes),
-        "supersteps": len(coalesce_transfer_steps(grid_plan).steps),
-        "layer_trace_ms": round(layer_s * 1e3, 1),
-        "segmented_trace_ms": round(seg_s * 1e3, 1),
-        "unrolled_trace_ms": round(unr_s * 1e3, 1),
-        "ratio_vs_layer": round(ratio, 3),
-        "speedup_vs_unrolled": round(unr_s / seg_s, 2),
-    })
-    print(
-        f"segmented gate: grid-sliced inception ({len(sdag.nodes)} tasks) "
-        f"m={m}: segmented {seg_s * 1e3:.0f}ms vs layer {layer_s * 1e3:.0f}ms "
-        f"({ratio:.2f}x; unrolled {unr_s * 1e3:.0f}ms, "
-        f"{unr_s / seg_s:.1f}x slower than segmented)"
-    )
-    assert ratio <= SEGMENTED_TRACE_FACTOR, (
-        f"segmented grid-sliced trace {seg_s * 1e3:.0f}ms not within "
-        f"{SEGMENTED_TRACE_FACTOR}x of layer-granularity "
-        f"{layer_s * 1e3:.0f}ms (ratio {ratio:.2f})"
-    )
-
-
-def bench_segmented_run_gate(results):
-    """Acceptance: segmented *runtime* parity on grid-sliced inception m=8.
-
-    Compiles both executors on the headline grid plan, then times them
-    interleaved — one warm-up dispatch each, then best-of-3 alternating
-    ``block_until_ready`` runs, so drift hits both sides equally.  Passes
-    when the segmented/unrolled ratio is within ``SEGMENTED_RUN_FACTOR``
-    *or* the segmented run is under ``SEGMENTED_RUN_FLOOR_MS`` absolute
-    (the bar that binds on 1-core hosts, where fake devices serialize and
-    the ratio drowns in dispatch noise).  Also asserts the two executors
-    agree numerically, so the gate doubles as an end-to-end equivalence
-    smoke on the exact configuration it times."""
-    import gc
-
-    import jax
-    import jax.numpy as jnp
-    from repro.core import dsh
-    from repro.core.costmodel import KEYSTONE_CPU
-    from repro.codegen import build_mpmd_executor
-    from repro.models.cnn import inception_net
-    from repro.models.slicing import slice_model, uniform_factors
-
-    gc.collect()
-    m = 8
-    if jax.device_count() < m:
-        print(f"segmented run gate: skipped ({jax.device_count()} devices)")
-        return
-    model = inception_net(64)
-    params = model.init_params(jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64, 3))
-    mesh = jax.make_mesh((m,), ("workers",))
-    base = uniform_factors(model, 8, spatial=True)
-    factors = {k: ((2, 4) if v == (1, 8) else v) for k, v in base.items()}
-    sliced = slice_model(model, factors)
-    sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
-    plan = build_plan(dsh(sdag, m), sdag)
-
-    f_seg = build_mpmd_executor(plan, sliced, params, mesh, batch=1,
-                                segmented=True)
-    f_unr = build_mpmd_executor(plan, sliced, params, mesh, batch=1)
-    y_seg = jax.block_until_ready(f_seg(x))   # warm-up = compile + 1st run
-    y_unr = jax.block_until_ready(f_unr(x))
-    err = float(jnp.abs(y_seg - y_unr).max())
-    assert err < 1e-5, f"segmented/unrolled diverge: maxerr {err:.2e}"
-
-    seg_ms = unr_ms = None
-    for _ in range(3):   # interleaved best-of-3: drift hits both sides
-        t0 = time.perf_counter()
-        jax.block_until_ready(f_seg(x))
-        dt = (time.perf_counter() - t0) * 1e3
-        seg_ms = dt if seg_ms is None else min(seg_ms, dt)
-        t0 = time.perf_counter()
-        jax.block_until_ready(f_unr(x))
-        dt = (time.perf_counter() - t0) * 1e3
-        unr_ms = dt if unr_ms is None else min(unr_ms, dt)
-    ratio = seg_ms / unr_ms
-    results.append({
-        "kind": "segmented_run_gate",
-        "model": "inception@grid2x4",
-        "n_workers": m,
-        "n_nodes": len(sdag.nodes),
-        "segmented_run_ms": round(seg_ms, 1),
-        "unrolled_run_ms": round(unr_ms, 1),
-        "ratio_vs_unrolled": round(ratio, 3),
-        "maxerr_vs_unrolled": err,
-    })
-    print(
-        f"segmented run gate: grid-sliced inception m={m}: "
-        f"segmented {seg_ms:.1f}ms vs unrolled {unr_ms:.1f}ms "
-        f"({ratio:.2f}x, floor {SEGMENTED_RUN_FLOOR_MS:.0f}ms)"
-    )
-    assert (ratio <= SEGMENTED_RUN_FACTOR
-            or seg_ms <= SEGMENTED_RUN_FLOOR_MS), (
-        f"segmented run {seg_ms:.1f}ms is {ratio:.2f}x unrolled "
-        f"{unr_ms:.1f}ms (> {SEGMENTED_RUN_FACTOR}x) and above the "
-        f"{SEGMENTED_RUN_FLOOR_MS:.0f}ms absolute floor"
-    )
-
-
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true",
@@ -799,15 +511,13 @@ def main():
                     help="committed baseline for the 2x trend gate")
     ap.add_argument("--density", type=float, default=0.10)
     ap.add_argument("--no-trace", action="store_true",
-                    help="skip the executor trace section")
+                    help="skip the streaming executor section")
     args = ap.parse_args()
 
     if args.quick:
         sizes, workers, ref_max = [100, 500], [2, 4], 100
-        trace_workers = [2]
     else:
         sizes, workers, ref_max = [100, 500, 1000, 2000], [2, 4, 8], 500
-        trace_workers = [2, 4, 8]
 
     results = []
     t_all = time.perf_counter()
@@ -847,16 +557,9 @@ def main():
         )
 
     if not args.no_trace:
-        # the gates run first so their best-of-3 timings see a fresh jax
-        # process state (the other trace sections leave dozens of compiled
-        # executors behind)
-        bench_segmented_trace_gate(results)
-        bench_segmented_run_gate(results)
         from stream_overlap import bench_stream_overlap
 
         bench_stream_overlap(results, args.quick)
-        bench_executor_trace(trace_workers, results)
-        bench_sliced_trace(trace_workers, results)
 
     # trend gate against the committed baseline, after every section has
     # appended its rows (the stream rows' staging bytes join the byte gate);

@@ -71,12 +71,12 @@ def _grid_inception():
 
 
 def staging_stats(plan, sliced, params, mesh, batch, depth):
-    """Static per-segment statistics of the segmented executor at one
-    buffer depth (built, not compiled)."""
+    """Static per-segment statistics of the executor at one buffer depth
+    (built, not compiled)."""
     from repro.codegen.executor import build_mpmd_executor
 
     f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                            segmented=True, buffer_depth=depth)
+                            buffer_depth=depth)
     return f.segment_stats
 
 

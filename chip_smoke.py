@@ -13,7 +13,7 @@ One chip (the default) runs the main path at the paper's full width
 3. jitted ``run_sequential``: compile, warm calls, error against a float32
    reference on the host CPU device of this process;
 4. the sliced DSH plan for m=1 (``search_slice_factors``), timed as the bare
-   ``build_mpmd_executor(segmented=True)`` call and as the ``checkpoint=True``
+   ``build_mpmd_executor`` call and as the ``checkpoint=True``
    executor that ``Frontend`` serves, then served through ``Frontend`` +
    ``attach_executor``: every request must run through the compiled
    executor, none may shed, and the audit against references from the same
@@ -192,14 +192,14 @@ def _outputs(seq, params, pool) -> np.ndarray:
 
 
 def time_executor(plan, sliced, params, devices, x1, ref, *, checkpoint: bool):
-    """Build, compile and time one segmented executor of ``plan`` over
+    """Build, compile and time one executor of ``plan`` over
     ``devices``; log its seconds and its error against ``ref`` (the same
     chip's sequential output for ``x1``)."""
     m = len(devices)
     mesh = jax.sharding.Mesh(np.asarray(devices), ("workers",))
     t = time.perf_counter()
     f = build_mpmd_executor(plan, sliced, params, mesh, batch=1,
-                            segmented=True, checkpoint=checkpoint)
+                            checkpoint=checkpoint)
     build_s = time.perf_counter() - t
     t = time.perf_counter()
     compiled = f.lower(x1).compile()

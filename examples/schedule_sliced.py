@@ -27,14 +27,12 @@ The TPU-priced paper-size run reproduces the 2-D acceptance number
     PYTHONPATH=src python examples/schedule_sliced.py \
         --model inception --input 224 --hw tpu --grid
 
-``--segmented`` additionally compiles the sliced plan through **both** MPMD
-executors — the unrolled superstep loop and the segmented ``lax.scan``
-executor (packed registers, per-segment kernel tables, ring comm rounds) —
-verifies they agree with the sequential reference, and reports the trace
-(lowering) time of each; on grid-sliced plans the segmented trace stays
-near layer-granularity cost while the unrolled one grows with task count.
+``--segmented`` additionally compiles the sliced plan through the MPMD
+executor (packed registers, per-segment kernel tables, ring comm rounds),
+verifies it against the sequential reference, and reports its trace
+(lowering) time.
 
-``--profile`` builds the segmented executor and prints each segment's
+``--profile`` builds the executor and prints each segment's
 static statistics (ticks, signatures, ring rounds, comm patterns, span
 and window-gather coverage, window elements and their gather indices,
 landing scans, columns landed per request and their efficiency) beside the whole call's warm best-of-3 wall time; with ``--grid`` it
@@ -48,7 +46,7 @@ benchmark builds it:
     PYTHONPATH=src python examples/schedule_sliced.py \
         --model googlenet --input 224 --hw tpu --grid --workers 1 --profile
 
-``--stream`` sweeps the segmented executor's ``buffer_depth`` knob
+``--stream`` sweeps the executor's ``buffer_depth`` option
 (1 = write-once staging, 2/4 = rotating double/quad-buffered staging
 frames + donated carry) and prints, per depth, the carry width, resident
 staging footprint, retire-copy volume and the warm call time.
@@ -166,15 +164,15 @@ def main():
     ap.add_argument("--skip-exec", action="store_true",
                     help="skip the numerical-equivalence execution check")
     ap.add_argument("--segmented", action="store_true",
-                    help="compile the sliced plan through the unrolled AND "
-                         "segmented MPMD executors, verify both against the "
-                         "sequential reference, and report trace times")
+                    help="compile the sliced plan through the MPMD "
+                         "executor, verify it against the sequential "
+                         "reference, and report its trace time")
     ap.add_argument("--profile", action="store_true",
                     help="per-segment static span/round statistics of the "
-                         "segmented executor and its warm best-of-3 call "
+                         "executor and its warm best-of-3 call "
                          "time; with --grid, the slice search's counters")
     ap.add_argument("--stream", action="store_true",
-                    help="buffer_depth sweep {1,2,4} of the segmented "
+                    help="buffer_depth sweep {1,2,4} of the "
                          "executor: per-depth carry width, staging "
                          "footprint, retire volume and warm call time")
     ap.add_argument("--analyze", action="store_true",
@@ -292,14 +290,13 @@ def main():
             return
         mesh = jax.make_mesh((args.workers,), ("workers",))
     if args.segmented:
-        for tag, kw in (("unrolled ", {}), ("segmented", {"segmented": True})):
-            f = build_mpmd_executor(plan, sliced, params, mesh, batch=2, **kw)
-            t0 = time.perf_counter()
-            f.lower(x)
-            trace_ms = (time.perf_counter() - t0) * 1e3
-            err = float(jnp.abs(f(x) - ref).max())
-            print(f"{tag} MPMD executor: trace {trace_ms:7.1f} ms, "
-                  f"max|y - sequential| = {err:.2e}")
+        f = build_mpmd_executor(plan, sliced, params, mesh, batch=2)
+        t0 = time.perf_counter()
+        f.lower(x)
+        trace_ms = (time.perf_counter() - t0) * 1e3
+        err = float(jnp.abs(f(x) - ref).max())
+        print(f"MPMD executor: trace {trace_ms:7.1f} ms, "
+              f"max|y - sequential| = {err:.2e}")
 
     if args.profile:
         profile_segments(plan, sliced, params, mesh, x, ref)
@@ -367,10 +364,9 @@ def profile_segments(plan, sliced, params, mesh, x, ref):
     Device time per phase comes from a profiler trace of the call: the
     executor's ops carry ``seg<k>/<phase>`` named scopes."""
     batch = x.shape[0]
-    f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                            segmented=True)
+    f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch)
     err = float(jnp.abs(f(x) - ref).max())
-    print(f"segmented executor: max|y - sequential| = {err:.2e}, "
+    print(f"MPMD executor: max|y - sequential| = {err:.2e}, "
           f"warm call {_best_ms(f, x):.2f} ms")
     print(f"{'seg':>4} {'steps':>9} {'ticks':>5} {'sigs':>4} {'rnds':>4} "
           f"{'pats':>4} {'cov':>5} {'win':>5} {'win_elems':>10} "
@@ -388,7 +384,7 @@ def profile_segments(plan, sliced, params, mesh, x, ref):
 def stream_report(plan, sliced, params, mesh, x, ref):
     """--stream satellite: buffer-depth sweep.
 
-    Builds the segmented executor at ``buffer_depth`` 1, 2 and 4 and
+    Builds the executor at ``buffer_depth`` 1, 2 and 4 and
     prints each depth's carry width, resident per-worker staging
     footprint (counted once, not per fire), retire-copy volume (columns
     moved home before a rotating frame is reused) and warm call time.
@@ -400,7 +396,7 @@ def stream_report(plan, sliced, params, mesh, x, ref):
           f"{'call':>8}  (ms)")
     for depth in (1, 2, 4):
         f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                                segmented=True, buffer_depth=depth)
+                                buffer_depth=depth)
         err = float(jnp.abs(f(x) - ref).max())
         assert err < 1e-4, f"depth {depth} diverged: {err:.2e}"
         st0 = f.segment_stats[0]

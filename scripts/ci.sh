@@ -6,12 +6,7 @@
 # reduction of halo-aware direct edges on sliced inception, the 2-D grid
 # acceptance (search_slice_factors' nested (cout x rows) tiling schedules
 # <= 0.9x the best uniform single-axis tiling on TPU-priced inception(224),
-# 8 workers), the segmented-executor trace acceptance (the lax.scan
-# executor traces grid-sliced inception within 5x of the layer-granularity
-# plan on 8 workers), the segmented *run* gate (warm interleaved best-of-3:
-# segmented runtime within 2x of the unrolled executor on the same grid
-# plan, or under the absolute-ms floor that binds on 1-core hosts where
-# fake devices serialize), the fault-drill smoke (a deterministic kill campaign
+# 8 workers), the fault-drill smoke (a deterministic kill campaign
 # on sliced lenet5: detect -> replan m-1 -> migrate registers -> resume,
 # resumed output asserted allclose to run_sequential), the serve-chaos
 # smoke (a seeded Poisson trace with deadlines/backpressure through the

@@ -42,21 +42,12 @@ TRACE_SCOPES: Dict[str, Set[str]] = {
         "worker_fn", "worker_fn_stream", "run_segment", "body", "idle",
         "branch", "mk_pat", "_run_all", "init_buf",
         "_gather_cols", "_scatter_cols", "_take_row",
-        "fused_comm", "per_node_comm",
     },
     "segment.py": {"kern"},
 }
 
-# (file, enclosing trace scope, rule) triples that are deliberate:
-# the unrolled reference executor's comm operates on dict-of-register
-# pytrees at trace-unroll time — its per-transfer indexing is the
-# certification-literal slow path, not a scan-body sink
-ALLOW: Set[Tuple[str, str, str]] = {
-    ("executor.py", "fused_comm", "fancy-index"),
-    ("executor.py", "per_node_comm", "fancy-index"),
-    ("executor.py", "fused_comm", "int-coercion"),
-    ("executor.py", "per_node_comm", "int-coercion"),
-}
+# (file, enclosing trace scope, rule) triples that are deliberate (none)
+ALLOW: Set[Tuple[str, str, str]] = set()
 
 MARKER = "trace-hygiene: ok"
 

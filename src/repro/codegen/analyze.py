@@ -702,8 +702,6 @@ def analyze_plan(
     *,
     depths: Sequence[int] = (1, 2, 4),
     checkpoint: bool = True,
-    liveness: bool = True,
-    cohort_rounds: bool = True,
     offsets: Optional[Dict[str, int]] = None,
     tamper: Optional[Callable] = None,
     max_hazards: int = 25,
@@ -738,8 +736,7 @@ def analyze_plan(
         for d in depths:
             try:
                 at = segment_access_tables(
-                    plan, model, liveness=liveness, buffer_depth=d,
-                    cohort_rounds=cohort_rounds, checkpoint=checkpoint,
+                    plan, model, buffer_depth=d, checkpoint=checkpoint,
                     offsets=offsets,
                 )
                 if tamper is not None:

@@ -1,55 +1,42 @@
-"""Plan execution: python interpreter (logic oracle) + shard_map MPMD executor.
+"""Plan execution: the python interpreter (the reference) and the compiled
+shard_map MPMD executor.
 
-The shard_map executor is the TPU realization of ACETONE's generated
+The compiled executor is the TPU realization of ACETONE's generated
 parallel C (paper §5.3): one mesh axis ``workers`` carries the m per-core
-programs as branches of a ``lax.switch`` on ``axis_index`` (MPMD-on-SPMD);
-each comm round becomes ``lax.ppermute`` collectives — the Writing/Reading
-flag protocol realized as dataflow edges, whose ordering guarantees are
+programs (MPMD-on-SPMD: every worker dispatches on ``axis_index``); comm
+rounds become ``lax.ppermute`` collectives — the Writing/Reading flag
+protocol realized as dataflow edges, whose ordering guarantees are
 enforced by construction.
 
 Register discipline: a **liveness pass** over the plan gives every layer
 output a birth superstep (first computed anywhere) and a death superstep
-(last read as a compute input or transfer payload); the register file
-carried across supersteps holds only the live buffers instead of one
-zero-initialized buffer per layer.  This keeps ACETONE's fully-static
-allocation story (every buffer's lifetime is known at generation time — the
-analogue of the paper's static per-layer output variables) while shrinking
-the per-worker footprint to the schedule's actual working set.
+(last read as a compute input or transfer payload), and
+``pack_registers`` lays the registers out in one packed buffer whose
+slots are reused once their occupant is dead.  This keeps ACETONE's
+fully-static allocation story (every buffer's lifetime is known at
+generation time — the analogue of the paper's static per-layer output
+variables) while shrinking the per-worker footprint to the schedule's
+actual working set.
 
-Communication discipline: instead of one tiny ``ppermute`` per communicated
-node, each superstep's transfers are grouped by ``(src, dst)`` worker pair,
-the pairs are split into permutation rounds with unique endpoints, and each
-round ships **one** flattened, concatenated payload per pair — one collective
-per round (the paper's per-channel Writing/Reading pairs, batched the way
-ACETONE's shared-memory ``comm_<src>_<dst>`` arrays batch a whole round).
-``fuse_transfers=False`` instead emits one collective per communicated
-(node, window) group — windowed transfers permute only the boxed slice and
-scatter it on arrival, so the executed volume equals the plan's
-``comm_bytes`` accounting exactly (:func:`executed_comm_bytes`).
+The executor consumes the plan-side canonicalization (``pack_registers``
++ ``build_segments`` in ``plan.py``) and lowers each
+:class:`~repro.codegen.plan.PlanSegment` to ``lax.scan`` loops whose carry
+is the packed register buffer and whose body is a single ``lax.switch`` over
+the segment's kernel table (structurally identical tile tasks share one
+traced branch — see :mod:`repro.codegen.segment`) followed by the
+segment's fixed ring-shift ``ppermute`` rounds, which gather padded index
+rows instead of tracing per-transfer slicing.  Program size is bounded by
+the number of *distinct* task structures, not the task count; results
+match ``interpret_plan`` (up to float reassociation in the convolutions).
 
-**Segmented executor** (``segmented=True``): the unrolled python loop above
-traces every superstep separately, so sliced plans with hundreds of tasks
-are trace-bound.  The segmented path instead consumes the plan-side
-canonicalization (``pack_registers`` + ``build_segments`` in ``plan.py``)
-and lowers each :class:`~repro.codegen.plan.PlanSegment` to **one**
-``lax.scan`` whose carry is the packed register buffer and whose body is a
-single ``lax.switch`` over the segment's kernel table (structurally
-identical tile tasks share one traced branch — see
-:mod:`repro.codegen.segment`) followed by the segment's fixed ring-shift
-``ppermute`` rounds, which gather/scatter padded index rows instead of
-tracing per-transfer slicing.  Program size is bounded by the number of
-*distinct* task structures, not the task count; results stay bit-exact
-against the unrolled path and ``interpret_plan``.
-
-Six runtime fast paths close the segmented path's per-call gap to the
-unrolled executor (which does static slices and exact payloads):
+Its runtime fast paths:
 
 * **value-returning dispatch** — switch branches return ``(y_pad,
   start)`` instead of threading the whole carry, and one outer
   ``dynamic_update_slice`` lands the result: the scan body never copies
   the register buffer through a conditional (on XLA:CPU a carry-threading
   ``lax.switch`` copies the full buffer per tick).  Branches pad their
-  output to the segment's max width with a *self-restoring tail* (a
+  output to their scan's landing width with a *self-restoring tail* (a
   dynamic_slice of the columns the write is about to overwrite), so the
   uniform-width write is exact;
 * **span-coalesced assembly** — fires per signature slot when
@@ -81,35 +68,22 @@ unrolled executor (which does static slices and exact payloads):
   their gather tables are statically redirected at build time through a
   per-worker ``home`` map, so no receive-side scatter or runtime
   indexing exists at all;
-* **baked parameters** (``bake_params=True``, off by default) —
-  occurrences are grouped by (structure, parameter tile), so every
-  branch's weights are trace-time constants and hit the same prepacked
-  XLA:CPU kernels (e.g. the Eigen convolution) as the unrolled path's
-  closed-over params; program size stays bounded by the number of
-  distinct parameter *tiles* (not tasks — row/grid slices of one layer
-  share a tile).  Off by default because doubling the branch count
-  roughly doubles segmented trace time for no measured runtime win on
-  serialized 1-core hosts; enable it on real multi-core targets where
-  the native conv kernels can pay for the lowering.  The default
-  jit-operand parameter tables index per occurrence;
-* **single-structure segments** — when a segment has exactly one
+* **single-structure runs** — when a landing run has exactly one
   signature and no idle (tick, worker) cells, every tick runs the same
   branch, so the ``lax.switch`` and its operand plumbing are skipped and
   the branch is called directly.
 
-``span_coalesce`` / ``cohort_rounds`` / ``bake_params`` toggle their fast
-path (ablation knobs; outputs are bit-identical in every combination),
-and the segmented executor's ops carry named scopes — ``seg<k>`` per
-segment and, inside it, ``assemble`` / ``params`` / ``kernel`` / ``land``
-/ ``retire`` / ``comm`` / ``checkpoint``, then ``output`` — so a profiler
-trace of the served program attributes device time per segment and per
-phase (``jax.named_scope`` only sets op metadata; the compiled program
-is the same).
+The executor's ops carry named scopes — ``seg<k>`` per segment and,
+inside it, ``assemble`` / ``params`` / ``kernel`` / ``land`` / ``retire``
+/ ``comm`` / ``checkpoint``, then ``output`` — so a profiler trace of the
+served program attributes device time per segment and per phase
+(``jax.named_scope`` only sets op metadata; the compiled program is the
+same).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -118,9 +92,7 @@ import numpy as np
 from repro.codegen.plan import (
     ExecutionPlan,
     RegisterLayout,
-    Superstep,
     Transfer,
-    _permutation_rounds,
     build_segments,
     coalesce_transfer_steps,
     pack_registers,
@@ -235,261 +207,6 @@ def interpret_plan(
     return regs[plan.sink_worker][plan.sink]
 
 
-# --------------------------------------------------------------------------- #
-# shard_map MPMD executor
-# --------------------------------------------------------------------------- #
-def build_mpmd_executor(
-    plan: ExecutionPlan,
-    model: CNNModel,
-    params,
-    mesh: jax.sharding.Mesh,
-    axis: str = "workers",
-    batch: int = 1,
-    liveness: bool = True,
-    fuse_transfers: bool = True,
-    coalesce: bool = True,
-    segmented: bool = False,
-    checkpoint: bool = False,
-    span_coalesce: bool = True,
-    cohort_rounds: bool = True,
-    bake_params: bool = False,
-    buffer_depth: int = 1,
-) -> Callable[[jax.Array], jax.Array]:
-    """Compile the plan into a jitted shard_map function ``f(x) -> y``.
-
-    ``mesh`` must have ``axis`` of size ``plan.n_workers``.  Input ``x`` and
-    output are replicated over the axis (P() specs); the result equals the
-    sequential reference on every worker (final broadcast via psum).  The
-    input's leading dimension must equal ``batch`` — it is baked into the
-    register layout, so the returned function validates it eagerly instead
-    of failing deep inside shard_map.
-
-    ``liveness=False`` carries the full per-layer register file across every
-    superstep (the original, certification-literal layout); ``liveness=True``
-    materializes registers at their birth superstep and drops them after
-    their death superstep.  ``fuse_transfers=False`` emits one ``ppermute``
-    per communicated (node, window) group per permutation round (the
-    original layout, now window-aware: boxed transfers ship exactly their
-    hull, matching :func:`executed_comm_bytes` to the plan's accounting);
-    ``fuse_transfers=True`` ships one flattened payload per ``(src, dst)``
-    pair and one collective per permutation round — windowed transfers
-    contribute only their consumed hull to the payload, so sliced plans'
-    fused payloads shrink to tile/halo intersections.  ``coalesce=True``
-    merges consecutive transfer-only supersteps into one comm round before
-    lowering (fewer unrolled supersteps to trace).
-
-    ``segmented=True`` swaps the unrolled superstep loop for the segmented
-    ``lax.scan`` executor (module docstring): registers live in one packed
-    buffer (``pack_registers``; ``liveness`` controls slot reuse), compute
-    dispatches through per-segment kernel tables, and comm becomes ring
-    rounds over padded index rows (``fuse_transfers`` does not apply).  The
-    unrolled path remains the certification-literal fallback and the
-    equivalence oracle for the segmented one.  ``span_coalesce`` /
-    ``cohort_rounds`` / ``bake_params`` (segmented only) are ablation
-    knobs for the span-assembly, cohort-round and constant-parameter fast
-    paths — outputs are bit-identical with them on or off.
-    ``buffer_depth`` (segmented only; default 1 = write-once staging)
-    selects the **streaming** mode at 2/4: comm payloads land in that many
-    rotating staging frames (double/quad buffering — superstep ``k+1``'s
-    ``ppermute`` fires land while tick ``k``'s deliveries are still being
-    read), still-live frame occupants are retired to their packed columns
-    before reuse, and the packed carry is **donated** across calls
-    (``donate_argnums`` + in-trace re-init) instead of re-materialized.
-    Outputs — and checkpoint snapshots' register region — are
-    bit-identical across depths; the carry width stops growing with the
-    plan's fire count and is bounded by ``buffer_depth`` × the largest
-    per-tick payload.
-
-    ``checkpoint=True`` (segmented only) makes the executor additionally
-    return its packed register carries at every segment boundary:
-    ``f(x) -> (y, snaps)`` with ``snaps`` of shape ``(n_segments,
-    n_workers, batch, width)`` — the fault-tolerant runtime's superstep
-    checkpoints, taken for free at the barriers the scan already
-    synchronizes on.  The returned callable exposes ``.layout`` (the
-    :class:`~repro.codegen.plan.RegisterLayout` of the carry, sentinel
-    columns excluded), ``.width`` and ``.segment_spans`` so recovery code
-    can interpret the snapshots without re-deriving the packing.
-    """
-    m = plan.n_workers
-    mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if axis not in mesh_axes:
-        raise KeyError(
-            f"mesh has no axis named {axis!r} (available axes: "
-            f"{tuple(mesh.axis_names)}); build the mesh with "
-            f"jax.make_mesh(({m},), ({axis!r},)) or pass the executor "
-            f"axis=<your axis name>"
-        )
-    if mesh_axes[axis] != m:
-        raise ValueError(
-            f"mesh axis {axis!r} has size {mesh_axes[axis]} but the plan "
-            f"schedules {m} workers; build the mesh with "
-            f"jax.make_mesh(({m},), ({axis!r},))"
-        )
-    if checkpoint and not segmented:
-        raise ValueError(
-            "checkpoint=True requires segmented=True: only the segmented "
-            "executor carries the packed register buffer that superstep "
-            "snapshots are defined over"
-        )
-    if not (isinstance(buffer_depth, int) and buffer_depth >= 1):
-        raise ValueError(
-            f"buffer_depth must be a positive int (1 = write-once staging, "
-            f"2/4 = double/quad-buffered streaming), got {buffer_depth!r}"
-        )
-    if buffer_depth != 1 and not segmented:
-        raise ValueError(
-            "buffer_depth >= 2 requires segmented=True: only the segmented "
-            "executor stages comm payloads in the packed carry that the "
-            "rotating frames double/quad-buffer"
-        )
-    if coalesce:
-        plan = coalesce_transfer_steps(plan)
-    if segmented:
-        return _build_segmented(
-            plan, model, params, mesh, axis, batch, liveness,
-            checkpoint=checkpoint, span_coalesce=span_coalesce,
-            cohort_rounds=cohort_rounds, bake_params=bake_params,
-            buffer_depth=buffer_depth,
-        )
-
-    reg_names = [l.name for l in model.layers]
-    reg_shapes = {
-        l.name: (batch, *l.out_shape) for l in model.layers
-    }
-    reg_sizes = {n: int(np.prod(reg_shapes[n])) for n in reg_names}
-
-    n_steps = len(plan.steps)
-    if liveness:
-        birth, death, _live = plan_liveness(plan, model)
-        born_at: List[List[str]] = [[] for _ in range(n_steps)]
-        dead_after: List[List[str]] = [[] for _ in range(n_steps)]
-        for b, bi in birth.items():
-            born_at[bi].append(b)
-            if death[b] < n_steps:
-                dead_after[death[b]].append(b)
-    else:
-        born_at = [[] for _ in range(n_steps)]
-        dead_after = [[] for _ in range(n_steps)]
-        if n_steps:
-            born_at[0] = list(reg_names)
-
-    def compute_branch(seg: Tuple[str, ...]):
-        """One worker's compute segment for one superstep."""
-
-        def run(regs: Dict[str, jax.Array], x: jax.Array) -> Dict[str, jax.Array]:
-            regs = dict(regs)
-            for name in seg:
-                spec = model.spec(name)
-                ins = [x] if spec.op == "input" else [regs[p] for p in spec.inputs]
-                regs[name] = apply_layer(spec, params, ins).astype(jnp.float32)
-            return regs
-
-        return run
-
-    def t_size(t: Transfer) -> int:
-        """Flattened payload elements of one transfer (incl. batch dim)."""
-        if t.box is None:
-            return reg_sizes[t.node]
-        n = batch
-        for lo, hi in t.box:
-            n *= hi - lo
-        return n
-
-    def fused_comm(regs: Dict[str, jax.Array], wid, transfers) -> None:
-        """One flattened ppermute per permutation round (mutates ``regs``).
-
-        Windowed transfers ship only their consumed hull — the payload per
-        ``(src, dst)`` pair is the concatenation of each transfer's window,
-        scattered back into the destination registers on arrival."""
-        pair_ts: Dict[Tuple[int, int], List[Transfer]] = {}
-        for t in transfers:
-            pair_ts.setdefault((t.src, t.dst), []).append(t)
-        for round_pairs in _permutation_rounds(sorted(pair_ts)):
-            length = max(
-                sum(t_size(t) for t in pair_ts[p]) for p in round_pairs
-            )
-            payload = jnp.zeros((length,), jnp.float32)
-            for (s, d) in round_pairs:
-                flat = jnp.concatenate([
-                    (
-                        regs[t.node]
-                        if t.box is None
-                        else regs[t.node][_box_index(t)]
-                    ).reshape(-1)
-                    for t in pair_ts[(s, d)]
-                ])
-                if flat.size < length:
-                    flat = jnp.pad(flat, (0, length - flat.size))
-                payload = jnp.where(wid == s, flat, payload)
-            moved = jax.lax.ppermute(payload, axis, round_pairs)
-            for (s, d) in round_pairs:
-                off = 0
-                for t in pair_ts[(s, d)]:
-                    sz = t_size(t)
-                    chunk = moved[off : off + sz]
-                    if t.box is None:
-                        val = chunk.reshape(reg_shapes[t.node])
-                    else:
-                        idx = _box_index(t)
-                        win = (batch, *(hi - lo for (lo, hi) in t.box))
-                        val = regs[t.node].at[idx].set(chunk.reshape(win))
-                    regs[t.node] = jnp.where(wid == d, val, regs[t.node])
-                    off += sz
-
-    def per_node_comm(regs: Dict[str, jax.Array], wid, transfers) -> None:
-        """Original layout: grouped ppermute per communicated (node, window)
-        group.  ppermute is a strict permutation, so a multicast (one src,
-        several dsts — the paper's repeated Writing ops, e.g. Write
-        0_2_a/0_3_a in Fig. 11) is split into sub-rounds with unique
-        endpoints.  Windowed transfers permute only the boxed slice and
-        scatter it into the destination register on arrival — shipping the
-        whole register would both disagree with ``ExecutionPlan.comm_bytes``
-        (the paper's per-channel byte accounting) and overwrite destination
-        windows that earlier rounds already materialized."""
-        by_key: Dict[Tuple[str, Optional[Tuple]], List[Transfer]] = {}
-        for t in transfers:
-            by_key.setdefault((t.node, t.box), []).append(t)
-        for (node, box), ts in sorted(
-            by_key.items(), key=lambda kv: (kv[0][0], kv[0][1] or ())
-        ):
-            idx = None if box is None else _box_index(ts[0])
-            for perm in _permutation_rounds([(t.src, t.dst) for t in ts]):
-                payload = regs[node] if idx is None else regs[node][idx]
-                moved = jax.lax.ppermute(payload, axis, perm)
-                dsts = jnp.asarray([d for (_s, d) in perm])
-                is_dst = jnp.any(wid == dsts)
-                val = moved if idx is None else regs[node].at[idx].set(moved)
-                regs[node] = jnp.where(is_dst, val, regs[node])
-
-    comm = fused_comm if fuse_transfers else per_node_comm
-
-    def worker_fn(x: jax.Array) -> jax.Array:
-        wid = jax.lax.axis_index(axis)
-        regs: Dict[str, jax.Array] = {}
-        for i, step in enumerate(plan.steps):
-            # materialize registers born this superstep (zeroed until the
-            # owning branch writes them — all switch branches must return
-            # the same pytree, so every live buffer exists on every worker)
-            for b in born_at[i]:
-                regs[b] = jnp.zeros(reg_shapes[b], jnp.float32)
-            if any(step.compute):  # sliced plans emit transfer-only rounds
-                branches = [compute_branch(seg) for seg in step.compute]
-                regs = jax.lax.switch(wid, branches, regs, x)
-            if step.transfers:
-                comm(regs, wid, step.transfers)
-            # retire registers whose last reader was this superstep
-            for b in dead_after[i]:
-                del regs[b]
-        # broadcast the sink value to all workers (replicated output)
-        out = jnp.where(wid == plan.sink_worker, regs[plan.sink], 0.0)
-        return jax.lax.psum(out, axis)
-
-    in_spec = jax.sharding.PartitionSpec()   # replicated input
-    out_spec = jax.sharding.PartitionSpec()  # replicated output
-    fn = _shard_map(worker_fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec)
-    return _with_batch_check(jax.jit(fn), batch)
-
-
 def _check_batch(x, batch: int) -> None:
     """Eager batch-dimension check shared by the executor wrappers."""
     lead = x.shape[0] if getattr(x, "ndim", 0) else None
@@ -510,7 +227,8 @@ def _with_batch_check(
     The batch size is baked into every register shape at build time; calling
     with a different leading dimension would otherwise surface as an opaque
     shard_map/switch shape mismatch from deep inside tracing.  The wrapper
-    exposes ``.lower`` (used by the trace benchmarks) with the same check.
+    exposes ``.lower`` (for inspecting the lowered program) with the same
+    check.
     """
 
     def run(x: jax.Array) -> jax.Array:
@@ -578,99 +296,49 @@ def executed_comm_bytes(
     plan: ExecutionPlan,
     model: CNNModel,
     batch: int = 1,
-    fuse_transfers: bool = True,
-    coalesce: bool = True,
     dtype_bytes: int = 4,
-    segmented: bool = False,
-    liveness: bool = True,
-    cohort_rounds: bool = True,
     buffer_depth: int = 1,
 ) -> float:
-    """Exact payload bytes the executors' collectives ship.
+    """Exact payload bytes the executor's collectives ship.
 
-    Mirrors the comm lowering analytically: the per-node path ships one
-    payload of the transfer's window per (node, window) group pair, so its
-    total equals ``plan.comm_bytes`` times ``batch * dtype_bytes`` /
-    producer-bytes — the byte-parity property the per-node window fix is
-    tested against.  The fused path pads each round's payload to the
-    round's largest pair, so it is an upper bound on the accounting.
-
-    ``segmented=True`` counts the segmented executor's cohort-sized ring
-    rounds instead (``fuse_transfers`` does not apply): only the *real*
-    (non-padding) entries of each active ``(tick, dst)`` index row — pad
-    entries gather from and scatter into the dump column, shipping no
-    register data — so the total is exactly ``plan.comm_bytes`` scaled by
-    ``batch * dtype_bytes`` / producer-bytes, whatever the cohort shapes.
-    ``buffer_depth`` only relocates where a payload *lands* (write-once
-    strip vs rotating frame): every delivery is counted exactly once here
-    whatever the depth — the streaming executor's extra retire copies are
-    local buffer moves, not shipped bytes — so the byte parity with
-    ``plan.comm_bytes`` holds at every depth.
+    Mirrors the comm lowering analytically: the cohort-sized ring rounds
+    ship only the *real* (non-padding) entries of each active ``(tick,
+    dst)`` index row — pad entries gather from and scatter into the dump
+    column, shipping no register data — so the total is exactly
+    ``plan.comm_bytes`` scaled by ``batch * dtype_bytes`` / producer-bytes,
+    whatever the cohort shapes.  ``buffer_depth`` only relocates where a
+    payload *lands* (write-once strip vs rotating frame): every delivery
+    is counted exactly once here whatever the depth — the streaming
+    executor's extra retire copies are local buffer moves, not shipped
+    bytes — so the byte parity with ``plan.comm_bytes`` holds at every
+    depth.
     """
-    if coalesce:
-        plan = coalesce_transfer_steps(plan)
-    sizes = {l.name: int(np.prod(l.out_shape)) for l in model.layers}
-    if segmented:
-        reg_shapes = {l.name: tuple(l.out_shape) for l in model.layers}
-        live = None
-        if liveness:
-            birth, death, _sets = plan_liveness(plan, model)
-            live = (birth, death)
-        offsets, total = pack_registers(
-            plan, {n: max(s, 1) for n, s in sizes.items()}, liveness=live
-        )
-        pad = total  # stand-in dump column; positions are in [0, total)
-        segments = build_segments(
-            plan, reg_shapes, offsets, pad_index=pad,
-            buffer_depth=buffer_depth,
-            **({} if cohort_rounds else {"cohort_ratio": None}),
-        )
-        real = 0
-        for seg in segments:
-            for r in seg.rounds:
-                per_row = (np.asarray(r.rows) != pad).sum(axis=1)
-                real += int(per_row[np.asarray(r.slot)].sum())
-        return float(real) * batch * dtype_bytes
-
-    def t_elems(t: Transfer) -> int:
-        if t.box is None:
-            return sizes[t.node]
-        n = 1
-        for lo, hi in t.box:
-            n *= hi - lo
-        return n
-
-    total = 0
-    for step in plan.steps:
-        if fuse_transfers:
-            pair_ts: Dict[Tuple[int, int], List[Transfer]] = {}
-            for t in step.transfers:
-                pair_ts.setdefault((t.src, t.dst), []).append(t)
-            for round_pairs in _permutation_rounds(sorted(pair_ts)):
-                length = max(
-                    sum(t_elems(t) for t in pair_ts[p]) for p in round_pairs
-                )
-                total += length * len(round_pairs)
-        else:
-            by_key: Dict[Tuple[str, Optional[Tuple]], List[Transfer]] = {}
-            for t in step.transfers:
-                by_key.setdefault((t.node, t.box), []).append(t)
-            for (_node, _box), ts in by_key.items():
-                e = t_elems(ts[0])
-                for perm in _permutation_rounds([(t.src, t.dst) for t in ts]):
-                    total += e * len(perm)
-    return float(total) * batch * dtype_bytes
+    plan = coalesce_transfer_steps(plan)
+    sizes = {l.name: max(int(np.prod(l.out_shape)), 1) for l in model.layers}
+    reg_shapes = {l.name: tuple(l.out_shape) for l in model.layers}
+    birth, death, _sets = plan_liveness(plan, model)
+    offsets, total = pack_registers(plan, sizes, liveness=(birth, death))
+    pad = total  # stand-in dump column; positions are in [0, total)
+    segments = build_segments(
+        plan, reg_shapes, offsets, pad_index=pad, buffer_depth=buffer_depth,
+    )
+    real = 0
+    for seg in segments:
+        for r in seg.rounds:
+            per_row = (np.asarray(r.rows) != pad).sum(axis=1)
+            real += int(per_row[np.asarray(r.slot)].sum())
+    return float(real) * batch * dtype_bytes
 
 
 # --------------------------------------------------------------------------- #
-# segmented scan executor
+# shard_map MPMD executor
 # --------------------------------------------------------------------------- #
 def _gather_cols(
     buf: jax.Array, idx: jax.Array, sorted_: bool = False
 ) -> jax.Array:
     """``buf[:, idx]`` as one raw ``lax.gather`` (no jnp indexing machinery —
     these gathers run once per switch branch and comm round, so their
-    tracing/lowering cost is the segmented executor's hot path).  ``idx``
+    tracing/lowering cost is the executor's hot path).  ``idx``
     must be in bounds (sentinel indices resolve to real buffer columns);
     comm rows are pre-sorted by the plan canonicalization."""
     dnums = jax.lax.GatherDimensionNumbers(
@@ -701,7 +369,7 @@ def _gather_windows(
     and shifts it left by its offset in the first row, one bit of the
     offset at a time (a barrel shifter of ``log2(LANES)`` selects, each
     trimming what no later step can reach).  The slab and its spare last
-    row are in bounds by construction (``_build_segmented``)."""
+    row are in bounds by construction (``build_mpmd_executor``)."""
     from repro.codegen.segment import LANES
 
     batch = buf.shape[0]
@@ -786,7 +454,7 @@ def _take_row(a: jax.Array, i: jax.Array) -> jax.Array:
     ``lax.dynamic_slice``-family ops canonicalize traced start indices
     through jnp ufuncs (a wrap-negative ``where(i < 0, i + n, i)`` per
     call); across hundreds of branch/table lookups that machinery, not the
-    math, dominated segmented trace time.  Indices here are known
+    math, dominated the executor's trace time.  Indices here are known
     non-negative, so a single PROMISE_IN_BOUNDS gather replaces it."""
     dnums = jax.lax.GatherDimensionNumbers(
         offset_dims=tuple(range(a.ndim - 1)),
@@ -827,7 +495,7 @@ def _waterfill(loads: np.ndarray, lo: int, hi: int, n: int) -> np.ndarray:
 
 @dataclasses.dataclass
 class PlanTables:
-    """Plan-side canonicalization shared by the segmented executor build
+    """Plan-side canonicalization shared by the executor build
     and the static analyzer (:mod:`repro.codegen.analyze`): packed register
     layout, sentinel regions, segment schema and per-node raw gather rows —
     all derived with numpy only.  One derivation serves both, so the
@@ -881,9 +549,7 @@ class AccessTables:
 def plan_tables(
     plan: ExecutionPlan,
     model: CNNModel,
-    liveness: bool = True,
     buffer_depth: int = 1,
-    cohort_rounds: bool = True,
     offsets: Optional[Dict[str, int]] = None,
 ) -> PlanTables:
     """Derive the packed layout, sentinel regions, raw gather rows and
@@ -898,8 +564,9 @@ def plan_tables(
     }
     birth, death, _sets = plan_liveness(plan, model)
     if offsets is None:
-        live = (birth, death) if liveness else None
-        offsets, total = pack_registers(plan, reg_sizes, liveness=live)
+        offsets, total = pack_registers(
+            plan, reg_sizes, liveness=(birth, death)
+        )
     else:
         total = max(offsets[n] + reg_sizes[n] for n in offsets)
 
@@ -929,7 +596,6 @@ def plan_tables(
     segments = build_segments(
         plan, reg_shapes, offsets, pad_index=dump_col,
         buffer_depth=buffer_depth,
-        **({} if cohort_rounds else {"cohort_ratio": None}),
     )
     return PlanTables(
         offsets=offsets, total=total, zero_base=zero_base,
@@ -1134,17 +800,14 @@ def segment_access_tables(
     plan: ExecutionPlan,
     model: CNNModel,
     *,
-    liveness: bool = True,
     buffer_depth: int = 1,
-    cohort_rounds: bool = True,
     checkpoint: bool = True,
     offsets: Optional[Dict[str, int]] = None,
 ) -> AccessTables:
     """The executor's access metadata for one plan at one ``buffer_depth``
     — the single entry point the happens-before analyzer consumes."""
     pt = plan_tables(
-        plan, model, liveness=liveness, buffer_depth=buffer_depth,
-        cohort_rounds=cohort_rounds, offsets=offsets,
+        plan, model, buffer_depth=buffer_depth, offsets=offsets,
     )
     access = plan_access_walk(
         plan, pt, buffer_depth=buffer_depth, checkpoint=checkpoint,
@@ -1157,7 +820,7 @@ def segment_access_tables(
 
 def _make_branch(
     sig, tab, x, batch: int, gin_kinds, pidx_identity: bool,
-    const_pops=None, wland: int = 1,
+    wland: int = 1,
 ):
     """One switch branch: assemble the signature's input blocks from the
     packed buffer through the occurrence's index tables, run the shared
@@ -1243,9 +906,7 @@ def _make_branch(
                 ins.append(jax.lax.reshape(flat, (batch, *shp)))
         with jax.named_scope("params"):
             pops = ()
-            if const_pops is not None:
-                pops = [jnp.asarray(p) for p in const_pops]
-            elif "p" in tab:
+            if "p" in tab:
                 pi = oc if pidx_identity else _take_row(tab["pidx"], oc)
                 pops = [_take_row(p, pi) for p in tab["p"]]
         with jax.named_scope("kernel"):
@@ -1268,55 +929,87 @@ def _make_branch(
     return branch
 
 
-def _build_segmented(
+def build_mpmd_executor(
     plan: ExecutionPlan,
     model: CNNModel,
     params,
     mesh: jax.sharding.Mesh,
-    axis: str,
-    batch: int,
-    liveness: bool,
+    axis: str = "workers",
+    batch: int = 1,
     checkpoint: bool = False,
-    span_coalesce: bool = True,
-    cohort_rounds: bool = True,
-    bake_params: bool = False,
     buffer_depth: int = 1,
 ) -> Callable[[jax.Array], jax.Array]:
-    """Segmented lax.scan lowering of a (coalesced) plan.
+    """Compile the plan into a jitted shard_map function ``f(x) -> y``.
 
-    Plan-side canonicalization (``pack_registers``/``build_segments``)
-    supplies the packed register layout and the per-segment tick/round
-    schema; this builder adds the model-side compute tables — per-segment
-    kernel lists keyed by structural signature, with per-occurrence operand
-    tables (register offsets, span starts, deduplicated parameter slices) —
-    and emits one scan per landing run of each segment
-    (``segment.land_runs``).  All tables are passed as jit arguments
-    rather than baked as constants, so tracing cost stays bounded by the
-    number of distinct signatures.
+    ``mesh`` must have ``axis`` of size ``plan.n_workers``.  Input ``x`` and
+    output are replicated over the axis (P() specs); the result equals the
+    sequential reference on every worker (final broadcast via psum).  The
+    input's leading dimension must equal ``batch`` — it is baked into the
+    register layout, so the returned function validates it eagerly instead
+    of failing deep inside shard_map.
 
-    ``span_coalesce=False`` keeps only the whole-slot-contiguous fast path
-    (everything else element-gathers — the pre-span layout);
-    ``cohort_rounds=False`` pads every ring round to the segment max (the
-    pre-cohort layout).  Both are ablation/debug knobs: outputs are
-    bit-identical across them.
+    The plan's consecutive transfer-only supersteps are merged first
+    (``coalesce_transfer_steps``, idempotent).  Plan-side canonicalization
+    (``pack_registers``/``build_segments``) supplies the packed register
+    layout and the per-segment tick/round schema; this builder adds the
+    model-side compute tables — per-segment kernel lists keyed by
+    structural signature, with per-occurrence operand tables (register
+    offsets, span starts, deduplicated parameter slices) — and emits one
+    scan per landing run of each segment (``segment.land_runs``).  All
+    tables are passed as jit arguments rather than baked as constants, so
+    tracing cost stays bounded by the number of distinct signatures.
 
-    ``buffer_depth >= 2`` is the **streaming** mode: comm payloads land in
-    that many rotating staging frames (``SegmentStaging``) instead of
-    write-once strips, per-tick retire tables copy a frame's still-live
-    occupants back to their packed columns before reuse, and the jitted
-    executor takes the previous call's final carry as a **donated**
-    argument (``donate_argnums``) re-initialized in-trace — so the packed
-    registers and staging frames are updated in place across calls instead
-    of re-materialized.  Outputs, and ``checkpoint`` snapshots' register
-    region, are bit-identical to depth 1.  The executor exposes
-    ``.segment_stats`` (static span/window/round tables per segment); its device
-    phases are named scopes (``_run_all``), so a profiler trace of the
-    served program splits its time by segment and phase.
+    Two options:
+
+    * ``checkpoint=True`` makes the executor additionally return its packed
+      register carries at every segment boundary: ``f(x) -> (y, snaps)``
+      with ``snaps`` of shape ``(n_segments, n_workers, batch, width)`` —
+      the fault-tolerant runtime's superstep checkpoints, taken at the
+      barriers the scan already synchronizes on.
+    * ``buffer_depth`` (default 1 = write-once staging) selects the
+      **streaming** mode at 2/4: comm payloads land in that many rotating
+      staging frames (``SegmentStaging``) instead of write-once strips,
+      per-tick retire tables copy a frame's still-live occupants back to
+      their packed columns before reuse, and the packed carry is
+      **donated** across calls (``donate_argnums`` + in-trace re-init)
+      instead of re-materialized.  Outputs, and checkpoint snapshots'
+      register region, are bit-identical across depths; the carry width
+      is bounded by ``buffer_depth`` × the largest per-tick payload.
+
+    The returned callable exposes ``.layout`` (the
+    :class:`~repro.codegen.plan.RegisterLayout` of the carry, sentinel
+    columns excluded), ``.width``, ``.segment_spans`` and
+    ``.checkpoint_steps`` so recovery code can interpret the snapshots
+    without re-deriving the packing, and ``.segment_stats`` (static
+    span/window/round tables per segment).  Its device phases are named
+    scopes (``_run_all``), so a profiler trace of the served program
+    splits its time by segment and phase.
     """
+    m = plan.n_workers
+    mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    if axis not in mesh_axes:
+        raise KeyError(
+            f"mesh has no axis named {axis!r} (available axes: "
+            f"{tuple(mesh.axis_names)}); build the mesh with "
+            f"jax.make_mesh(({m},), ({axis!r},)) or pass the executor "
+            f"axis=<your axis name>"
+        )
+    if mesh_axes[axis] != m:
+        raise ValueError(
+            f"mesh axis {axis!r} has size {mesh_axes[axis]} but the plan "
+            f"schedules {m} workers; build the mesh with "
+            f"jax.make_mesh(({m},), ({axis!r},))"
+        )
+    if not (isinstance(buffer_depth, int) and buffer_depth >= 1):
+        raise ValueError(
+            f"buffer_depth must be a positive int (1 = write-once staging, "
+            f"2/4 = double/quad-buffered streaming), got {buffer_depth!r}"
+        )
+    plan = coalesce_transfer_steps(plan)
+
     from repro.codegen.segment import (
         LANES,
         LAND_SCAN_COST,
-        SpanTable,
         coalesce_spans,
         coalesce_windows,
         land_runs,
@@ -1325,16 +1018,11 @@ def _build_segmented(
         resolve_rows,
     )
 
-    m = plan.n_workers
     # plan-side canonicalization + the build-time schedule walk (shared
     # with codegen/analyze.py, which verifies these exact tables)
-    pt = plan_tables(
-        plan, model, liveness=liveness, buffer_depth=buffer_depth,
-        cohort_rounds=cohort_rounds,
-    )
+    pt = plan_tables(plan, model, buffer_depth=buffer_depth)
     offsets, total = pt.offsets, pt.total
     reg_shapes, reg_sizes = pt.reg_shapes, pt.reg_sizes
-    raw_rows = pt.raw_rows
     zero_base, neginf_base = pt.zero_base, pt.neginf_base
     dump_col, nrun = pt.dump_col, pt.nrun
     segments = pt.segments
@@ -1420,10 +1108,9 @@ def _build_segmented(
                 if node is None:
                     continue
                 sig, pkey = sig_of(node)
-                key = (sig, pkey) if bake_params else sig
-                sid = sig_index.get(key)
+                sid = sig_index.get(sig)
                 if sid is None:
-                    sid = sig_index[key] = len(sig_list)
+                    sid = sig_index[sig] = len(sig_list)
                     sig_list.append(sig)
                     occs.append({"gin": [], "out": [], "pidx": [],
                                  "uniq": {}, "parrs": []})
@@ -1450,25 +1137,9 @@ def _build_segmented(
                     np.stack([r[j] for r in o["gin"]]),
                     zero_base, neginf_base,
                 )
-                span = None
-                if rows.shape[1]:
-                    if span_coalesce:
-                        span = coalesce_spans(rows)
-                    else:
-                        # pre-span fast path: only whole-slot-contiguous
-                        # rows become a (single-span) dynamic_slice
-                        runs = rows[:, :1] + np.arange(
-                            rows.shape[1], dtype=np.int32
-                        )
-                        if (rows == runs).all():
-                            span = SpanTable(
-                                lens=(rows.shape[1],), kinds=("span",),
-                                starts=rows[:, :1].copy(),
-                                rem=np.zeros((rows.shape[0], 0), np.int32),
-                                coverage=1.0,
-                            )
+                span = coalesce_spans(rows) if rows.shape[1] else None
                 win = None
-                if span is None and span_coalesce:
+                if span is None:
                     # scattered past the span thresholds: equal windows?
                     win = coalesce_windows(rows)
                 gather_elems += rows.size
@@ -1497,28 +1168,17 @@ def _build_segmented(
                 "out": jnp.asarray(np.asarray(o["out"], np.int32)),
             }
             pidx_identity = True
-            const_pops = None
             if o["parrs"]:
-                if bake_params and len(o["parrs"]) == 1:
-                    # one parameter tile serves every occurrence (the
-                    # bake_params branch split guarantees this): bake it as
-                    # a trace-time constant so XLA prepacks/fuses the weights
-                    # the way the unrolled path's closed-over params do,
-                    # instead of tracing a dynamic-operand kernel
-                    const_pops = tuple(o["parrs"][0])
-                else:
-                    pidx = np.asarray(o["pidx"], np.int32)
-                    pidx_identity = bool(
-                        (pidx == np.arange(len(pidx))).all()
-                    )
-                    if not pidx_identity:
-                        tab["pidx"] = jnp.asarray(pidx)
-                    tab["p"] = tuple(
-                        jnp.asarray(np.stack([pa[j] for pa in o["parrs"]]))
-                        for j in range(len(o["parrs"][0]))
-                    )
+                pidx = np.asarray(o["pidx"], np.int32)
+                pidx_identity = bool((pidx == np.arange(len(pidx))).all())
+                if not pidx_identity:
+                    tab["pidx"] = jnp.asarray(pidx)
+                tab["p"] = tuple(
+                    jnp.asarray(np.stack([pa[j] for pa in o["parrs"]]))
+                    for j in range(len(o["parrs"][0]))
+                )
             sig_tabs.append(tab)
-            sig_infos.append((tuple(gin_kinds), pidx_identity, const_pops))
+            sig_infos.append((tuple(gin_kinds), pidx_identity))
         lmax = max(
             [0] + [
                 sum(seg.rounds[r].length for r in pat) for pat in patterns

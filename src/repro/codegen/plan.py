@@ -334,7 +334,7 @@ def coalesce_transfer_steps(plan: ExecutionPlan) -> ExecutionPlan:
 
     Sliced plans emit rounds where every worker is blocked on remote data
     and no one computes; each such round costs the executor one more
-    unrolled superstep (and one more collective) for no compute.  Because
+    tick (and one more collective) for no compute.  Because
     suppliers are always workers that *computed* the value (build_plan),
     a transfer's source register never depends on an earlier transfer in
     the same or the previous round, so consecutive transfer-only rounds —
@@ -842,13 +842,17 @@ def _step_round_positions(
     }
 
 
+# largest over smallest per-destination payload within one ring-round
+# cohort (``build_segments``): a cohort pads its round to its own max
+COHORT_RATIO = 4.0
+
+
 def build_segments(
     plan: ExecutionPlan,
     reg_shapes: Mapping[str, Tuple[int, ...]],
     offsets: Mapping[str, int],
     pad_index: int,
     split_ratio: float = 16.0,
-    cohort_ratio: Optional[float] = 4.0,
     buffer_depth: int = 1,
 ) -> List[PlanSegment]:
     """Canonicalize ``plan`` into uniformly-shaped :class:`PlanSegment`\\ s.
@@ -865,14 +869,12 @@ def build_segments(
     Ring rounds are sized per **tick cohort**, not per segment: for each
     ring delta, the ticks that actually ship bytes are grouped into cohorts
     whose largest and smallest per-destination payloads differ by at most
-    ``cohort_ratio``, and each cohort becomes its own :class:`CommRound`
-    padded only to the *cohort* max (``cohort_ratio=None`` restores one
-    segment-max round per delta — the pre-cohort layout, kept as an
-    ablation/debug knob).  Rounds that would ship nothing anywhere — fully
-    padded, e.g. every payload of a delta empty — are elided here at build
-    time instead of surviving as runtime ``lax.cond``-skipped rounds:
-    every emitted round has ``length >= 1`` and at least one active
-    ``(tick, dst)`` cell.
+    :data:`COHORT_RATIO`, and each cohort becomes its own
+    :class:`CommRound` padded only to the *cohort* max.  Rounds that would
+    ship nothing anywhere — fully padded, e.g. every payload of a delta
+    empty — are elided here at build time instead of surviving as
+    runtime ``lax.cond``-skipped rounds: every emitted round has ``length
+    >= 1`` and at least one active ``(tick, dst)`` cell.
 
     ``buffer_depth`` selects the staging layout attached as each segment's
     ``stage`` (see :class:`SegmentStaging`): 1 (default) is the write-once
@@ -948,13 +950,9 @@ def build_segments(
             floor = 0
             for t, dsts in ship:
                 sc = max(len(p) for p in dsts.values())
-                if cohort_ratio is not None and (
-                    not cohorts or sc > cohort_ratio * floor
-                ):
+                if not cohorts or sc > COHORT_RATIO * floor:
                     cohorts.append([])
                     floor = sc
-                elif not cohorts:
-                    cohorts.append([])
                 cohorts[-1].append((t, dsts))
             for members in cohorts:
                 length = max(

@@ -1,9 +1,9 @@
-"""Structural compute signatures + kernel table for the segmented executor.
+"""Structural compute signatures + kernel table for the MPMD executor.
 
-The unrolled MPMD executor traces ``apply_layer`` once per (node, superstep)
-occurrence, so sliced plans' trace time grows with the task count.  The
-segmented executor instead dispatches every tick through **one**
-``lax.switch`` over a table of *kernels*, each traced once per segment — so
+Tracing ``apply_layer`` once per (node, superstep) occurrence would make
+sliced plans' trace time grow with the task count.  The executor instead
+dispatches every tick through **one** ``lax.switch`` over a table of
+*kernels*, each traced once per segment — so
 two tasks that are structurally identical (same op, same pads/stride, same
 operand block shapes) share a single branch, and everything that
 distinguishes them travels as data:
@@ -20,8 +20,7 @@ distinguishes them travels as data:
 * **register identities** become buffer offsets in those rows;
 * **parameter values** become stacked operand arrays, pre-sliced host-side
   (numpy) exactly the way ``apply_layer`` slices them in-trace (e.g. a
-  ``conv_slice``'s ``w[..., c_lo:c_hi]`` column block), so the kernel math
-  is bit-identical to the unrolled path.
+  ``conv_slice``'s ``w[..., c_lo:c_hi]`` column block).
 
 :func:`node_signature` abstracts a :class:`LayerSpec` into ``(sig, pkey)``:
 ``sig = (op_sig, slot_shapes)`` is the hashable structural signature (a full
@@ -320,7 +319,6 @@ def make_kernel(sig: Sig) -> Callable:
     input window already folded into the gather rows."""
     op_sig, _slot_shapes = sig
     kind = op_sig[0]
-    dn = ("NHWC", "HWIO", "NHWC")
 
     if kind == "input":
         return lambda x, ins, pops: x
@@ -333,51 +331,36 @@ def make_kernel(sig: Sig) -> Callable:
         kh, kw, cin, cout = wsh
 
         def kern(x, ins, pops):
+            # patches + GEMM instead of conv_general_dilated: the weights
+            # arrive as jit operands (table-indexed, not trace constants),
+            # and XLA:CPU lowers a dynamic-filter convolution through a
+            # slow generic path while a dynamic-rhs dot stays on the fast
+            # Eigen contraction.  kh*kw static slices + one concat
+            # reproduce im2col exactly (dy-major, dx, cin — the same
+            # flattening order as the HWIO filter reshape).
             w_, b_ = pops
             xi = ins[0]
-            if isinstance(w_, jax.core.Tracer):
-                # patches + GEMM instead of conv_general_dilated: when the
-                # weights arrive as jit operands (table-indexed, not trace
-                # constants) XLA:CPU lowers a dynamic-filter convolution
-                # through a slow generic path while a dynamic-rhs dot
-                # stays on the fast Eigen contraction.  kh*kw static
-                # slices + one concat reproduce im2col exactly (dy-major,
-                # dx, cin — the same flattening order as the HWIO filter
-                # reshape).
-                wl, wr = wpads
-                if wl or wr:
-                    xi = jax.lax.pad(
-                        xi, jnp.float32(0),
-                        ((0, 0, 0), (0, 0, 0), (wl, wr, 0), (0, 0, 0)),
-                    )
-                bsz, h, w, _c = xi.shape
-                ho = (h - kh) // s + 1
-                wo = (w - kw) // s + 1
-                cols = [
-                    jax.lax.slice(
-                        xi, (0, dy, dx, 0),
-                        (bsz, dy + (ho - 1) * s + 1,
-                         dx + (wo - 1) * s + 1, cin),
-                        (1, s, s, 1),
-                    )
-                    for dy in range(kh) for dx in range(kw)
-                ]
-                p = (
-                    cols[0] if len(cols) == 1
-                    else jax.lax.concatenate(cols, 3)
+            wl, wr = wpads
+            if wl or wr:
+                xi = jax.lax.pad(
+                    xi, jnp.float32(0),
+                    ((0, 0, 0), (0, 0, 0), (wl, wr, 0), (0, 0, 0)),
                 )
-                w2 = jax.lax.reshape(w_, (kh * kw * cin, cout))
-                y = jax.lax.dot_general(
-                    p, w2, (((3,), (0,)), ((), ()))
-                ) + b_
-                return jax.nn.relu(y)
-            # constant (baked) weights take the native convolution — the
-            # same Eigen fast path the unrolled executor's closed-over
-            # params hit
-            y = jax.lax.conv_general_dilated(
-                xi, w_, (s, s), ((0, 0), wpads),
-                dimension_numbers=dn,
-            ) + b_
+            bsz, h, w, _c = xi.shape
+            ho = (h - kh) // s + 1
+            wo = (w - kw) // s + 1
+            cols = [
+                jax.lax.slice(
+                    xi, (0, dy, dx, 0),
+                    (bsz, dy + (ho - 1) * s + 1,
+                     dx + (wo - 1) * s + 1, cin),
+                    (1, s, s, 1),
+                )
+                for dy in range(kh) for dx in range(kw)
+            ]
+            p = cols[0] if len(cols) == 1 else jax.lax.concatenate(cols, 3)
+            w2 = jax.lax.reshape(w_, (kh * kw * cin, cout))
+            y = jax.lax.dot_general(p, w2, (((3,), (0,)), ((), ()))) + b_
             return jax.nn.relu(y)
         return kern
     if kind == "pool":

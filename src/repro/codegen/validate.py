@@ -58,7 +58,7 @@ executes.
 from __future__ import annotations
 
 import hashlib
-from typing import Dict, Mapping, Optional, Sequence, Set, Tuple
+from typing import Dict, Mapping, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -200,7 +200,7 @@ def _check_boxes(plan: ExecutionPlan, shapes: Mapping[str, Tuple[int, ...]]) -> 
 def _check_layout(
     plan: ExecutionPlan,
     layout: RegisterLayout,
-    liveness: Optional[Tuple[Mapping[str, int], Mapping[str, int]]],
+    liveness: Tuple[Mapping[str, int], Mapping[str, int]],
 ) -> None:
     regs = sorted(layout.offsets)
     for n in regs:
@@ -211,8 +211,6 @@ def _check_layout(
                 f"of {layout.total} elements (register sizing)",
                 register=n, column=off,
             )
-    if liveness is None:
-        return
     birth, death = liveness
     for i, a in enumerate(regs):
         oa, sa = layout.offsets[a], layout.size(a)
@@ -557,7 +555,6 @@ def validate_plan(
     plan: ExecutionPlan,
     dag: DAG,
     model=None,
-    liveness: bool = True,
     *,
     deep: bool = False,
     staging_depths: Sequence[int] = (1, 2, 4),
@@ -585,7 +582,7 @@ def validate_plan(
     if cache:
         key = (
             plan_fingerprint(plan), _dag_fingerprint(dag),
-            _model_fingerprint(model), liveness, deep,
+            _model_fingerprint(model), deep,
             tuple(staging_depths),
         )
         hit = _MEMO.get(key)
@@ -595,12 +592,10 @@ def validate_plan(
     if model is not None:
         shapes = {l.name: tuple(l.out_shape) for l in model.layers}
         _check_boxes(plan, shapes)
-        live = None
-        if liveness:
-            from repro.codegen.executor import plan_liveness
+        from repro.codegen.executor import plan_liveness
 
-            birth, death, _sets = plan_liveness(plan, model)
-            live = (birth, death)
+        birth, death, _sets = plan_liveness(plan, model)
+        live = (birth, death)
         layout = RegisterLayout.of(plan, shapes, liveness=live)
         _check_layout(plan, layout, live)
         _check_segments(plan, layout, staging_depths)
@@ -611,7 +606,7 @@ def validate_plan(
 
         report = analyze_plan(
             plan, dag, model, depths=tuple(staging_depths),
-            liveness=liveness, raise_on_hazard=True,
+            raise_on_hazard=True,
         )
         stats["hazards"] = 0
         stats["analyzed_events"] = (

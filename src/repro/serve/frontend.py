@@ -39,14 +39,13 @@ simulated clock (the DAG's time unit), so an identical seed replays the
 identical outcome — statuses, latencies, shed reasons and outputs.
 
 Fault-free steady-state ticks can optionally run through the *compiled*
-checkpointed segmented executor instead of the numpy superstep runner
-(:meth:`Frontend.attach_executor`): executors are cached on the full knob
-tuple — batch-size bucket plus ``(buffer_depth, span_coalesce,
-cohort_rounds, bake_params)`` — so re-attaching with different knobs never
-reuses a stale compile.  Rows are padded to the bucket, and every run
-returns the packed segment-boundary snapshots (``.checkpoint_steps`` on
-the executor) that recovery code migrates exactly like the runner's
-barriers.
+checkpointed executor instead of the numpy superstep runner
+(:meth:`Frontend.attach_executor`): executors are cached on ``(bucket,
+buffer_depth)`` — the batch-size bucket and the staging depth — so
+re-attaching with another depth never reuses a stale compile.  Rows are
+padded to the bucket, and every run returns the packed segment-boundary
+snapshots (``.checkpoint_steps`` on the executor) that recovery code
+migrates exactly like the runner's barriers.
 """
 from __future__ import annotations
 
@@ -266,8 +265,8 @@ class Frontend:
         self._step_times = _step_compute_times(self.plan, dag)
         self._devices = None
         self._buckets: Tuple[int, ...] = ()
-        self._exec_knobs = (1, True, True, False)
-        self._exec_cache: Dict[Tuple, object] = {}
+        self._buffer_depth = 1
+        self._exec_cache: Dict[Tuple[int, int], object] = {}
         for w in range(m):
             self.monitor.heartbeat(w)
 
@@ -684,29 +683,27 @@ class Frontend:
     # ---- compiled-executor fast path ---------------------------------- #
     def attach_executor(
         self, devices=None, buckets: Sequence[int] = (1, 2, 4, 8),
-        buffer_depth: int = 1, span_coalesce: bool = True,
-        cohort_rounds: bool = True, bake_params: bool = False,
+        buffer_depth: int = 1,
     ) -> None:
-        """Route fault-free ticks through the checkpointed segmented
-        executor (``build_mpmd_executor(segmented=True, checkpoint=True)``)
-        instead of the numpy superstep runner.
+        """Route fault-free ticks through the checkpointed executor
+        (``build_mpmd_executor(checkpoint=True)``) instead of the numpy
+        superstep runner.
 
         Executors are compiled lazily per batch-size bucket and cached
-        under the **full knob tuple** ``(bucket, buffer_depth,
-        span_coalesce, cohort_rounds, bake_params)`` — re-attaching with
-        different knobs can never silently reuse a stale compiled
-        executor, and the knobs are forwarded verbatim to
-        ``build_mpmd_executor`` (``buffer_depth >= 2`` streams: rotating
-        staging frames + donated carry; outputs are bit-identical across
-        depths, so serving results don't depend on the knob).  A replan
-        invalidates the cache (the new plan re-compiles on its surviving
-        device prefix).  Each run stores its segment-boundary snapshots on
-        ``self.last_snapshot`` — the same packed carries the runner's
-        barriers produce (proven in ``tests/test_faults.py``), so
-        recovery migrates them identically (``executor.checkpoint_steps``
-        names the superstep each snapshot is the entering barrier of).
-        Chaos runs (any injected fault) always take the runner path, which
-        is the only interruptible one."""
+        under ``(bucket, buffer_depth)`` — re-attaching with another depth
+        can never silently reuse a stale compiled executor, and the depth
+        is forwarded verbatim to ``build_mpmd_executor`` (``buffer_depth
+        >= 2`` streams: rotating staging frames + donated carry; outputs
+        are bit-identical across depths, so serving results don't depend
+        on it).  A replan invalidates the cache (the new plan re-compiles
+        on its surviving device prefix).  Each run stores its
+        segment-boundary snapshots on ``self.last_snapshot`` — the same
+        packed carries the runner's barriers produce (proven in
+        ``tests/test_faults.py``), so recovery migrates them identically
+        (``executor.checkpoint_steps`` names the superstep each snapshot
+        is the entering barrier of).  Chaos runs (any injected fault)
+        always take the runner path, which is the only interruptible
+        one."""
         devices = list(jax.devices() if devices is None else devices)
         if len(devices) < self.plan.n_workers:
             raise ValueError(
@@ -719,16 +716,12 @@ class Frontend:
             )
         self._devices = devices
         self._buckets = tuple(sorted(set(int(b) for b in buckets)))
-        self._exec_knobs = (
-            int(buffer_depth), bool(span_coalesce), bool(cohort_rounds),
-            bool(bake_params),
-        )
+        self._buffer_depth = int(buffer_depth)
         self._exec_cache.clear()
 
     def _executor(self, rows: int):
         bucket = next(b for b in self._buckets if b >= rows)
-        depth, span, cohort, bake = self._exec_knobs
-        key = (bucket, depth, span, cohort, bake)
+        key = (bucket, self._buffer_depth)
         f = self._exec_cache.get(key)
         if f is None:
             from repro.codegen.executor import build_mpmd_executor
@@ -741,9 +734,7 @@ class Frontend:
                        tick=self.runs):
                 f = build_mpmd_executor(
                     self.plan, self.model, self.params, mesh, batch=bucket,
-                    segmented=True, checkpoint=True, buffer_depth=depth,
-                    span_coalesce=span, cohort_rounds=cohort,
-                    bake_params=bake,
+                    checkpoint=True, buffer_depth=self._buffer_depth,
                 )
             self._exec_cache[key] = f
         return f, bucket

@@ -284,7 +284,7 @@ params = model.init_params(key)
 x = jax.random.normal(key, (2, 28, 28, 1))
 mesh = jax.make_mesh((4,), ("workers",))
 ys = [build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                          segmented=True, buffer_depth=d)(x)
+                          buffer_depth=d)(x)
       for d in (1, 3)]
 assert bool((ys[0] == ys[1]).all())
 print("DEPTH3_BITID_OK")

@@ -160,6 +160,58 @@ class TestLivenessAndCoalescing:
             assert float(jnp.abs(y - ref).max()) == 0.0
 
 
+    def test_executor_coalesces_the_plan_it_is_given(self, subproc):
+        """The executor coalesces transfer-only supersteps itself, and
+        coalescing is idempotent: a plan with one superstep's transfers
+        split off into a transfer-only superstep, that plan coalesced (as
+        ``Frontend`` serves it), and the original all build the same
+        executor — lowered program, carry width, segments and stats."""
+        out = subproc("""
+import dataclasses
+import jax, numpy as np
+from repro.codegen import (
+    Superstep, build_plan, build_mpmd_executor, coalesce_transfer_steps,
+)
+from repro.core import dsh
+from repro.core.costmodel import KEYSTONE_CPU
+from repro.models.cnn import lenet5
+from repro.models.slicing import slice_model, uniform_factors
+
+model = lenet5(28)
+sliced = slice_model(model, uniform_factors(model, 4))
+sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
+plan = build_plan(dsh(sdag, 4), sdag)
+assert coalesce_transfer_steps(plan) is plan
+i = next(i for i, s in enumerate(plan.steps) if len(s.transfers) > 1)
+st = plan.steps[i]
+half = len(st.transfers) // 2
+split = dataclasses.replace(plan, steps=(
+    *plan.steps[:i],
+    Superstep(compute=st.compute, transfers=st.transfers[:half]),
+    Superstep(compute=((),) * 4, transfers=st.transfers[half:]),
+    *plan.steps[i + 1:],
+))
+served = coalesce_transfer_steps(split)
+assert len(split.steps) == len(plan.steps) + 1
+assert served.steps == plan.steps
+params = model.init_params(jax.random.PRNGKey(0))
+mesh = jax.make_mesh((4,), ("workers",))
+x = np.zeros((1, 28, 28, 1), np.float32)
+built = [build_mpmd_executor(p, sliced, params, mesh, batch=1,
+                             checkpoint=True)
+         for p in (plan, split, served)]
+texts = {f.lower(x).as_text() for f in built}
+assert len(texts) == 1
+for f in built[1:]:
+    assert f.width == built[0].width
+    assert f.segment_spans == built[0].segment_spans
+    assert f.checkpoint_steps == built[0].checkpoint_steps
+    assert f.segment_stats == built[0].segment_stats
+print("COALESCED_BUILD_OK")
+""", devices=4)
+        assert "COALESCED_BUILD_OK" in out
+
+
 class TestRender:
     def test_pseudo_c_contains_protocol(self):
         model = inception_net(64)
@@ -195,6 +247,41 @@ for m in (2, 4):
 print("MPMD_OK")
 """, devices=4)
         assert "MPMD_OK" in out
+
+    @pytest.mark.parametrize("heur", ["ish", "dsh"])
+    def test_sliced_ish_and_dsh_plans_match_interpreter_subprocess(
+        self, subproc, heur
+    ):
+        """ISH plans (no duplicated tasks) as well as DSH plans (duplicated
+        tasks, fewer transfers) of a channel-sliced inception net through
+        the executor at m = 2 and 4, against ``interpret_plan`` and the
+        sequential reference."""
+        out = subproc(f"""
+import jax, jax.numpy as jnp
+from repro.models.cnn import inception_net, run_sequential
+from repro.models.slicing import slice_model, uniform_factors
+from repro.core import {heur}
+from repro.core.costmodel import KEYSTONE_CPU
+from repro.codegen import build_plan, build_mpmd_executor, interpret_plan
+key = jax.random.PRNGKey(0)
+model = inception_net(64)
+params = model.init_params(key)
+x = jax.random.normal(key, (2, 64, 64, 3))
+ref = run_sequential(model, params, x)
+sliced = slice_model(model, uniform_factors(model, 2))
+sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
+for m in (2, 4):
+    plan = build_plan({heur}(sdag, m), sdag)
+    assert plan.n_transfers, m
+    mesh = jax.make_mesh((m,), ("workers",))
+    f = build_mpmd_executor(plan, sliced, params, mesh, batch=2)
+    y = f(x)
+    yi = interpret_plan(plan, sliced, params, x)
+    assert float(jnp.abs(y - yi).max()) < 1e-5, m
+    assert float(jnp.abs(y - ref).max()) < 1e-4, m
+print("HEUR_MPMD_OK")
+""", devices=4)
+        assert "HEUR_MPMD_OK" in out
 
 
 _PARAM_DIGEST = """

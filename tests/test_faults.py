@@ -243,18 +243,6 @@ class TestKillAndResumeDrill:
 # checkpointing executor: barrier carries match the superstep runner
 # --------------------------------------------------------------------------- #
 class TestCheckpointExecutor:
-    def test_checkpoint_requires_segmented(self):
-        from repro.core.schedule import single_worker_schedule
-        model = lenet5()
-        dag = model.to_dag(KEYSTONE_CPU, time_unit=1e-6)
-        plan = build_plan(single_worker_schedule(dag), dag)
-        params = model.init_params(KEY)
-        mesh = jax.make_mesh((1,), ("workers",))
-        from repro.codegen.executor import build_mpmd_executor
-        with pytest.raises(ValueError, match="segmented"):
-            build_mpmd_executor(plan, model, params, mesh, batch=1,
-                                checkpoint=True)
-
     def test_checkpoint_snapshots_match_runner(self, subproc):
         out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
@@ -278,7 +266,7 @@ sliced = slice_model(model, uniform_factors(model, m))
 sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
 plan = coalesce_transfer_steps(build_plan(dsh(sdag, m), sdag))
 f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                        segmented=True, checkpoint=True)
+                        checkpoint=True)
 y, snaps = f(x)
 assert float(jnp.abs(y - ref).max()) < 1e-4
 total = f.layout.total
@@ -447,8 +435,8 @@ class TestWCETCertificate:
 
 
 # --------------------------------------------------------------------------- #
-# checkpoint snapshots are invariant under the runtime knobs, and every
-# knob's snapshot resumes correctly after a kill at any segment boundary
+# checkpoint snapshots are invariant under the buffer depth, and every
+# depth's snapshot resumes correctly after a kill at any segment boundary
 # --------------------------------------------------------------------------- #
 class TestCheckpointKnobInvariance:
     def test_snapshots_bit_identical_across_knobs_and_resume(self, subproc):
@@ -478,26 +466,27 @@ plan = coalesce_transfer_steps(build_plan(dsh(sdag, m), sdag))
 layout = _plan_layout(plan, sliced)
 total = layout.total
 
-# knob matrix: snapshots (and output) bit-identical in the register region.
-# buffer_depth >= 2 streams deliveries through rotating staging frames and
-# donates the carry, but retire-on-evict materializes every live value back
-# into its packed column before a frame rotates — snapshots [:total] must
-# stay byte-equal to the depth-1 (write-once staging) executor.
+# option matrix: snapshots (and output) bit-identical in the register
+# region.  buffer_depth >= 2 streams deliveries through rotating staging
+# frames and donates the carry, but retire-on-evict materializes every live
+# value back into its packed column before a frame rotates — snapshots
+# [:total] must stay byte-equal to the depth-1 (write-once staging)
+# executor, and the output must not depend on whether snapshots are taken.
 ref_y = ref_snaps = spans = None
-for cr, bp, depth in itertools.product(
-        (True, False), (True, False), (1, 2, 4)):
+for depth, ckpt in itertools.product((1, 2, 4), (True, False)):
     f = build_mpmd_executor(plan, sliced, params, mesh, batch=batch,
-                            segmented=True, checkpoint=True,
-                            cohort_rounds=cr, bake_params=bp,
-                            buffer_depth=depth)
+                            checkpoint=ckpt, buffer_depth=depth)
+    if not ckpt:
+        assert (np.asarray(f(x)) == ref_y).all(), depth
+        continue
     y, snaps = f(x)
     regs = np.asarray(snaps[:, :, :, :total])
     if ref_y is None:
         ref_y, ref_snaps, spans = np.asarray(y), regs, f.segment_spans
     else:
-        assert (np.asarray(y) == ref_y).all(), (cr, bp, depth)
-        assert f.segment_spans == spans, (cr, bp, depth)
-        assert (regs == ref_snaps).all(), (cr, bp, depth)
+        assert (np.asarray(y) == ref_y).all(), depth
+        assert f.segment_spans == spans, depth
+        assert (regs == ref_snaps).all(), depth
     if depth == 4:
         stream_snaps = regs
 

@@ -98,7 +98,7 @@ def test_lenet5_one_run_per_segment_same_program(monkeypatch):
 
     def build():
         return build_mpmd_executor(plan, sliced, params, mesh, batch=1,
-                                   segmented=True, checkpoint=True)
+                                   checkpoint=True)
 
     f = build()
     assert all(s["land_runs"] == 1 for s in f.segment_stats)
@@ -134,8 +134,7 @@ def run(scan_cost):
     segment.LAND_SCAN_COST = scan_cost
     try:
         f = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                segmented=True, checkpoint=True,
-                                buffer_depth=DEPTH)
+                                checkpoint=True, buffer_depth=DEPTH)
     finally:
         segment.LAND_SCAN_COST = chosen
     y, snaps = f(x)

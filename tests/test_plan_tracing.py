@@ -46,8 +46,7 @@ x = np.zeros((1, *model.layers[0].out_shape), np.float32)
 
 def compiled():
     f = build_mpmd_executor(plan, sliced, params, mesh, batch=1,
-                            segmented=True, checkpoint=True,
-                            buffer_depth=depth)
+                            checkpoint=True, buffer_depth=depth)
     return f.lower(x).compile().as_text()
 
 def program(text):

@@ -1,7 +1,8 @@
-"""Segmented scan executor: equivalence vs interpret_plan and the unrolled
-executor, plan canonicalization properties (packing, padding), and the
+"""Segmented scan executor: equivalence vs interpret_plan and the
+sequential reference, plan canonicalization properties (packing, padding,
+cohort rounds), comm byte parity with the plan's accounting, and the
 window-semantics bugfix sweep (duplicate-parent hulls, multi-sink guard,
-window-aware per-node comm, batch/axis validation)."""
+batch/axis validation)."""
 import json
 
 import jax
@@ -290,10 +291,13 @@ def test_multi_sink_dag_raises():
 
 
 # --------------------------------------------------------------------------- #
-# satellite: per-node comm is window-aware — byte parity with the plan
+# comm byte parity: the executor ships exactly the plan's accounting
 # --------------------------------------------------------------------------- #
 class TestCommByteParity:
-    def test_per_node_path_matches_plan_accounting(self):
+    def test_boxed_transfers_match_plan_accounting(self):
+        """Windowed (boxed) transfers of a spatial row tiling ship only
+        their consumed hull: the ring rounds' real entries equal the
+        plan's per-channel byte accounting, and batch scales them."""
         model = inception_net(64)
         sliced = slice_model(model, uniform_factors(model, 4, spatial=True))
         sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
@@ -301,25 +305,18 @@ class TestCommByteParity:
         out_bytes = {l.name: l.out_bytes() for l in sliced.layers}
         boxed = [t for s in plan.steps for t in s.transfers if t.box is not None]
         assert boxed, "expected windowed transfers on a spatial tiling"
-        per_node = executed_comm_bytes(plan, sliced, fuse_transfers=False)
-        assert per_node == plan.comm_bytes(out_bytes)
-        # batch scales the payloads linearly
-        assert executed_comm_bytes(
-            plan, sliced, batch=3, fuse_transfers=False
-        ) == 3 * per_node
-        # the fused path pads each round to its largest pair
-        assert executed_comm_bytes(plan, sliced, fuse_transfers=True) >= per_node
+        got = executed_comm_bytes(plan, sliced)
+        assert got == plan.comm_bytes(out_bytes)
+        assert executed_comm_bytes(plan, sliced, batch=3) == 3 * got
 
     def test_layer_granularity_parity(self):
         model = inception_net(64)
         dag = model.to_dag(KEYSTONE_CPU, time_unit=1e-6)
         plan = build_plan(dsh(dag, 4), dag)
         out_bytes = {l.name: l.out_bytes() for l in model.layers}
-        assert executed_comm_bytes(
-            plan, model, fuse_transfers=False
-        ) == plan.comm_bytes(out_bytes)
+        assert executed_comm_bytes(plan, model) == plan.comm_bytes(out_bytes)
 
-    def test_segmented_cohort_rounds_match_plan_accounting(self):
+    def test_segmented_cohort_round_rows_match_plan_accounting(self):
         """The segmented executor's ring rounds pad every index row to the
         round's length, but pad entries gather from and scatter into the
         dump column — the *real* entries must total exactly the plan's
@@ -330,13 +327,9 @@ class TestCommByteParity:
         plan = build_plan(dsh(sdag, 8), sdag)
         out_bytes = {l.name: l.out_bytes() for l in sliced.layers}
         want = plan.comm_bytes(out_bytes)
-        for cohort in (True, False):
-            got = executed_comm_bytes(
-                plan, sliced, segmented=True, cohort_rounds=cohort)
-            assert got == want, (cohort, got, want)
-        # batch scales the payloads linearly, like the unrolled paths
-        assert executed_comm_bytes(
-            plan, sliced, batch=3, segmented=True) == 3 * want
+        assert executed_comm_bytes(plan, sliced) == want
+        # batch scales the payloads linearly
+        assert executed_comm_bytes(plan, sliced, batch=3) == 3 * want
 
     def test_segmented_buffer_depths_match_plan_accounting(self):
         """Rotating staging frames (buffer_depth >= 2) re-land deliveries in
@@ -352,12 +345,11 @@ class TestCommByteParity:
         out_bytes = {l.name: l.out_bytes() for l in sliced.layers}
         want = plan.comm_bytes(out_bytes)
         for depth in (1, 2, 4):
-            got = executed_comm_bytes(
-                plan, sliced, segmented=True, buffer_depth=depth)
+            got = executed_comm_bytes(plan, sliced, buffer_depth=depth)
             assert got == want, (depth, got, want)
         # batch scaling is depth-independent too
         assert executed_comm_bytes(
-            plan, sliced, batch=3, segmented=True, buffer_depth=4
+            plan, sliced, batch=3, buffer_depth=4
         ) == 3 * want
 
 
@@ -373,11 +365,16 @@ class TestExecutorValidation:
         mesh = jax.make_mesh((1,), ("workers",))
         return model, dag, plan, params, mesh, kw
 
-    @pytest.mark.parametrize("segmented", [False, True])
-    def test_wrong_batch_raises_actionable_error(self, segmented):
+    @pytest.mark.parametrize("checkpoint", [False, True])
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_wrong_batch_raises_actionable_error(self, depth, checkpoint):
+        """Both wrappers check the batch eagerly: the write-once
+        executor's (depth 1) and the streaming one's (depth 2, which
+        feeds its carry back), with and without snapshots."""
         model, _dag, plan, params, mesh, _ = self._build()
         f = build_mpmd_executor(
-            plan, model, params, mesh, batch=2, segmented=segmented
+            plan, model, params, mesh, batch=2, checkpoint=checkpoint,
+            buffer_depth=depth,
         )
         with pytest.raises(ValueError, match=r"batch=2.*batch=3"):
             f(jnp.zeros((3, 28, 28, 1)))
@@ -386,7 +383,8 @@ class TestExecutorValidation:
         # the right batch still runs
         x = jax.random.normal(KEY, (2, 28, 28, 1))
         ref = run_sequential(model, params, x)
-        assert float(jnp.abs(f(x) - ref).max()) < 1e-5
+        y = f(x)[0] if checkpoint else f(x)
+        assert float(jnp.abs(y - ref).max()) < 1e-5
 
     def test_missing_mesh_axis_raises_keyerror(self):
         model, _dag, plan, params, _mesh, _ = self._build()
@@ -456,22 +454,20 @@ for model, factors in cases:
     sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
     plan = build_plan(dsh(sdag, m), sdag)
     yi = interpret_plan(plan, sliced, params, x)
-    f_seg = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                segmented=True)
-    f_unr = build_mpmd_executor(plan, sliced, params, mesh, batch=2)
-    y_seg, y_unr = f_seg(x), f_unr(x)
+    f_seg = build_mpmd_executor(plan, sliced, params, mesh, batch=2)
+    y_seg = f_seg(x)
     assert float(jnp.abs(y_seg - ref).max()) < 1e-4, model.name
-    # segmented vs the oracles: exact up to 1-ulp boundary-tile conv
+    # against the interpreter: exact up to 1-ulp boundary-tile conv
     # reassociation (virtualized halo rows vs XLA pad attributes)
     assert float(jnp.abs(y_seg - yi).max()) < 1e-5, model.name
-    assert float(jnp.abs(y_seg - y_unr).max()) < 1e-5, model.name
 print("SEG_EQUIV_OK")
 """, devices=8)
         assert "SEG_EQUIV_OK" in out
 
     def test_segmented_flag_matrix_and_windowed_per_node(self, subproc):
-        """lookahead x coalesce on the segmented path, liveness off, plus
-        the window-aware fuse_transfers=False path on a halo tiling."""
+        """Eager and literal (``lookahead``) plans of a halo row tiling,
+        whose transfers are windowed, against ``interpret_plan`` and the
+        sequential reference."""
         out = subproc("""
 import jax, jax.numpy as jnp
 from repro.codegen import build_plan, interpret_plan
@@ -492,28 +488,11 @@ sliced = slice_model(model, uniform_factors(model, 4, spatial=True))
 sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
 for lookahead in (True, False):
     plan = build_plan(dsh(sdag, m), sdag, lookahead=lookahead)
-    for coalesce in (True, False):
-        f_seg = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                    segmented=True, coalesce=coalesce)
-        f_unr = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                    coalesce=coalesce)
-        err = float(jnp.abs(f_seg(x) - f_unr(x)).max())
-        assert err < 1e-5, (lookahead, coalesce, err)
-        assert float(jnp.abs(f_seg(x) - ref).max()) < 1e-4
-
-plan = build_plan(dsh(sdag, m), sdag)
-f_live0 = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                              segmented=True, liveness=False)
-assert float(jnp.abs(f_live0(x) - ref).max()) < 1e-4
-
-# window-aware per-node comm: boxed transfers ship only their hull
-boxed = [t for s in plan.steps for t in s.transfers if t.box is not None]
-assert boxed
-f_pn = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                           fuse_transfers=False)
-yi = interpret_plan(plan, sliced, params, x)
-assert float(jnp.abs(f_pn(x) - yi).max()) == 0.0
-assert float(jnp.abs(f_pn(x) - ref).max()) < 1e-4
+    assert [t for s in plan.steps for t in s.transfers if t.box is not None]
+    y = build_mpmd_executor(plan, sliced, params, mesh, batch=2)(x)
+    yi = interpret_plan(plan, sliced, params, x)
+    assert float(jnp.abs(y - yi).max()) < 1e-5, lookahead
+    assert float(jnp.abs(y - ref).max()) < 1e-4, lookahead
 print("SEG_MATRIX_OK")
 """, devices=8)
         assert "SEG_MATRIX_OK" in out
@@ -534,7 +513,7 @@ ref = run_sequential(model, params, x)
 dag = model.to_dag(KEYSTONE_CPU, time_unit=1e-6)
 plan = build_plan(dsh(dag, 2), dag)
 mesh = jax.make_mesh((2,), ("workers",))
-f = build_mpmd_executor(plan, model, params, mesh, batch=2, segmented=True)
+f = build_mpmd_executor(plan, model, params, mesh, batch=2)
 assert float(jnp.abs(f(x) - ref).max()) < 1e-4
 print("SEG_LAYER_OK")
 """, devices=2)
@@ -545,7 +524,7 @@ print("SEG_LAYER_OK")
 # satellite: cohort-sized ring rounds — dead rounds elided at build time
 # --------------------------------------------------------------------------- #
 class TestCohortRounds:
-    def _segments(self, cohort_rounds=True):
+    def _segments(self):
         model = inception_net(64)
         sliced = slice_model(model, grid_factors(model))
         sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
@@ -555,9 +534,8 @@ class TestCohortRounds:
         reg_shapes = {l.name: tuple(l.out_shape) for l in sliced.layers}
         birth, death, _sets = plan_liveness(plan, sliced)
         offsets, total = pack_registers(plan, sizes, liveness=(birth, death))
-        kw = {} if cohort_rounds else {"cohort_ratio": None}
-        return build_segments(plan, reg_shapes, offsets, pad_index=total,
-                              **kw), total
+        return build_segments(plan, reg_shapes, offsets,
+                              pad_index=total), total
 
     def test_no_dead_rounds_survive_build(self):
         """Cohort splitting may leave a round with no active (tick, dst)
@@ -596,31 +574,25 @@ class TestCohortRounds:
                 by_delta[r.delta] = active
         assert split, "expected at least one cohort-split delta"
 
-    def test_cohorts_preserve_shipped_entries(self):
-        """Cohort splitting rearranges rounds but must ship exactly the
-        same (tick, delta, dst) -> positions multiset as the unsplit
-        schema."""
-        def entries(segs, pad):
-            got = {}
-            for seg in segs:
-                for r in seg.rounds:
-                    slot = np.asarray(r.slot)
-                    rows = np.asarray(r.rows)
-                    for t in range(slot.shape[0]):
-                        for dst in range(slot.shape[1]):
-                            rid = slot[t, dst]
-                            if rid == 0:
-                                continue
-                            row = rows[rid]
-                            key = (seg.start + t, r.delta, dst)
-                            vals = sorted(row[row != pad].tolist())
-                            got.setdefault(key, []).extend(vals)
-            return {k: sorted(v) for k, v in got.items()}
+    def test_cohort_payloads_within_ratio(self):
+        """A cohort round pads only to its own widest payload: within one
+        round, every shipping tick's widest per-destination payload lies
+        within ``COHORT_RATIO`` of the narrowest's, and the round is
+        exactly as long as the widest."""
+        from repro.codegen.plan import COHORT_RATIO
 
-        on, pad = self._segments(cohort_rounds=True)
-        off, pad2 = self._segments(cohort_rounds=False)
-        assert pad == pad2
-        assert entries(on, pad) == entries(off, pad)
+        segs, pad = self._segments()
+        checked = 0
+        for seg in segs:
+            for r in seg.rounds:
+                slot = np.asarray(r.slot)
+                real = (np.asarray(r.rows) != pad).sum(axis=1)
+                scales = [int(real[slot[t]].max())
+                          for t in range(slot.shape[0]) if slot[t].any()]
+                assert max(scales) == r.length, seg.start
+                assert max(scales) <= COHORT_RATIO * min(scales), seg.start
+                checked += 1
+        assert checked
 
 
 # --------------------------------------------------------------------------- #
@@ -897,13 +869,14 @@ class TestWindowGather:
 
 
 # --------------------------------------------------------------------------- #
-# satellite: runtime knobs are bit-identical ablations
+# the executor's options are bit-identical in their output
 # --------------------------------------------------------------------------- #
 class TestKnobBitIdentity:
     def test_segmented_knobs_bit_identical(self, subproc):
-        """span_coalesce / cohort_rounds / bake_params rearrange the trace,
-        never the arithmetic: all knob settings produce bit-identical
-        outputs (same kernels, same operand values, same order)."""
+        """``buffer_depth`` and ``checkpoint`` rearrange staging and add
+        snapshot outputs, never the arithmetic: every setting produces
+        bit-identical outputs (same kernels, same operand values, same
+        order) on a grid-sliced plan."""
         out = subproc("""
 import itertools
 import jax, jax.numpy as jnp
@@ -927,17 +900,14 @@ sdag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
 plan = build_plan(dsh(sdag, m), sdag)
 
 ref = None
-for sc, cr, bp in itertools.product((True, False), repeat=3):
-    for depth in (1, 2):
-        fn = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                 segmented=True, span_coalesce=sc,
-                                 cohort_rounds=cr, bake_params=bp,
-                                 buffer_depth=depth)
-        y = fn(x)
-        if ref is None:
-            ref = y
-        else:
-            assert bool((y == ref).all()), (sc, cr, bp, depth)
+for depth, ckpt in itertools.product((1, 2), (False, True)):
+    fn = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
+                             checkpoint=ckpt, buffer_depth=depth)
+    y = fn(x)[0] if ckpt else fn(x)
+    if ref is None:
+        ref = y
+    else:
+        assert bool((y == ref).all()), (depth, ckpt)
 print("KNOB_BITID_OK")
 """, devices=4, timeout=900)
         assert "KNOB_BITID_OK" in out
@@ -982,8 +952,7 @@ for name, (model, ffn, m) in CASES.items():
     total = _plan_layout(plan, sliced).total
     for depth in (1, 2, 4):
         f = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                segmented=True, checkpoint=True,
-                                buffer_depth=depth)
+                                checkpoint=True, buffer_depth=depth)
         y, snaps = f(x)
         h = hashlib.sha256()
         h.update(np.asarray(y).tobytes())

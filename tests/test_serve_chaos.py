@@ -277,9 +277,9 @@ print("EXEC_TICK_OK", fe.exec_runs, fe.runs)
         assert "EXEC_TICK_OK" in out
 
     def test_executor_cache_keyed_on_knob_tuple(self, subproc):
-        """Re-attaching with different knobs (here ``buffer_depth``) must
-        never reuse a stale compiled executor: the cache is keyed on the
-        full knob tuple and cleared on attach, and results stay
+        """Re-attaching with another ``buffer_depth`` must never reuse a
+        stale compiled executor: the cache is keyed on ``(bucket,
+        buffer_depth)`` and cleared on attach, and results stay
         bit-identical across depths."""
         out = subproc("""
 import numpy as np
@@ -299,7 +299,7 @@ keys = {}
 for depth in (1, 2):
     fe = Frontend(sliced, params, dag, m=4, hw=KEYSTONE_CPU)
     fe.attach_executor(buffer_depth=depth)
-    assert fe._exec_knobs == (depth, True, True, False)
+    assert fe._buffer_depth == depth
     assert not fe._exec_cache, "attach must clear the cache"
     trace = poisson_trace(4, seed=5, rate=2.0 / fe.est_service,
                           service=fe.est_service)
@@ -308,7 +308,7 @@ for depth in (1, 2):
     keys[depth] = set(fe._exec_cache)
     prints[depth] = fe.fingerprint()
 assert keys[1] != keys[2]
-assert all(k[1] == d for d in keys for k in keys[d]), keys
+assert all(len(k) == 2 and k[1] == d for d in keys for k in keys[d]), keys
 assert prints[1] == prints[2]
 print("KNOB_CACHE_OK")
 """, devices=4)
@@ -335,7 +335,7 @@ params = model.init_params(jax.random.PRNGKey(0))
 plan = build_plan(dsh(dag, 4), dag)
 mesh = jax.make_mesh((4,), ("workers",))
 f = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                        segmented=True, checkpoint=True)
+                        checkpoint=True)
 x = np.random.default_rng(0).standard_normal(
     (2, *model.layers[0].out_shape)).astype(np.float32)
 y, snaps = f(x)
@@ -352,3 +352,43 @@ for k, stop in enumerate(f.checkpoint_steps):
 print("CKPT_STEPS_OK")
 """, devices=4)
         assert "CKPT_STEPS_OK" in out
+
+    def test_served_executor_equals_direct_build(self, subproc):
+        """What ``Frontend`` serves is ``build_mpmd_executor(plan,
+        checkpoint=True)`` on its plan: the same lowered program, and each
+        request's output and snapshots bit for bit."""
+        out = subproc("""
+import math
+import numpy as np
+import jax
+from repro.codegen import build_mpmd_executor
+from repro.core.costmodel import KEYSTONE_CPU
+from repro.models.cnn import lenet5
+from repro.models.slicing import slice_model, uniform_factors
+from repro.serve import Frontend, FrontendConfig, TraceRequest, input_pool
+
+model = lenet5()
+sliced = slice_model(model, uniform_factors(model, 4, spatial=True))
+dag = sliced.to_dag(KEYSTONE_CPU, time_unit=1e-6)
+params = model.init_params(jax.random.PRNGKey(0))
+pool = input_pool(model.layers[0].out_shape, 4, seed=3)
+fe = Frontend(sliced, params, dag, m=4, hw=KEYSTONE_CPU,
+              cfg=FrontendConfig(max_rows=1))
+fe.attach_executor(buckets=(1,))
+mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("workers",))
+g = build_mpmd_executor(fe.plan, sliced, params, mesh, batch=1,
+                        checkpoint=True)
+for rid in range(3):
+    r = fe.submit(TraceRequest(rid, fe.now, 1, rid, math.inf), pool)
+    fe.step()
+    assert r.status == "done", r.status
+    snaps, f = fe.last_snapshot
+    y_g, snaps_g = g(pool[rid:rid + 1])
+    assert r.output.tobytes() == np.asarray(y_g).tobytes(), rid
+    assert snaps.tobytes() == np.asarray(snaps_g).tobytes(), rid
+x = pool[:1]
+assert f.lower(x).as_text() == g.lower(x).as_text()
+assert fe.exec_runs == 3
+print("SERVED_EQ_DIRECT_OK")
+""", devices=4)
+        assert "SERVED_EQ_DIRECT_OK" in out

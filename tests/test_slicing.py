@@ -112,8 +112,8 @@ class TestNumericalEquivalence:
 
     def test_sliced_mpmd_matches_sequential_subprocess(self, subproc):
         """Direct-edge sliced plans through the real shard_map executor
-        (windowed fused transfers) for a CNN, a branchy CNN with halo row
-        tiles, an inception net and the transformer block."""
+        (windowed transfers in ring rounds) for a CNN, a branchy CNN with
+        halo row tiles, an inception net and the transformer block."""
         out = subproc("""
 import jax, jax.numpy as jnp
 from repro.models.cnn import (

@@ -281,7 +281,7 @@ class TestEquivalence:
             assert float(jnp.abs(y - ref).max()) < 1e-4, m
 
     def test_grid_mpmd_matches_sequential_subprocess(self, subproc):
-        """2-D grid plans — windowed fused transfers over nested tilings —
+        """2-D grid plans — windowed transfers over nested tilings —
         through the real shard_map executor."""
         out = subproc("""
 import jax, jax.numpy as jnp

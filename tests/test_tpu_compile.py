@@ -93,7 +93,7 @@ def test_sliced_m1_checkpoint_executor_compiles(
     sliced, plan = _sliced_plan(model, 1)
     mesh = Mesh(np.asarray(topo.devices[:1]), ("workers",))
     f = build_mpmd_executor(
-        plan, sliced, params, mesh, batch=1, segmented=True, checkpoint=True
+        plan, sliced, params, mesh, batch=1, checkpoint=True
     )
     compiled = f.lower(_input(NamedSharding(mesh, PartitionSpec()))).compile()
     assert compiled.memory_analysis() is not None
@@ -108,7 +108,7 @@ def test_sliced_m4_executor_compiles_with_collective_permute(
     mesh = Mesh(np.asarray(topo.devices[:4]), ("workers",))
     # checkpoint=True: the executor Frontend(m=4) serves through
     f = build_mpmd_executor(
-        plan, sliced, params, mesh, batch=1, segmented=True, checkpoint=True
+        plan, sliced, params, mesh, batch=1, checkpoint=True
     )
     compiled = f.lower(_input(NamedSharding(mesh, PartitionSpec()))).compile()
     assert "collective-permute" in compiled.as_text()

@@ -31,12 +31,11 @@ mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:M]), ("workers",))
 with_windows = segment.coalesce_windows
 
 
-def run(span_coalesce=True, windows=True):
+def run(windows=True):
     segment.coalesce_windows = (
         with_windows if windows else lambda rows, **kw: None)
     try:
-        f = build_mpmd_executor(plan, sliced, params, mesh, batch=2,
-                                segmented=True, span_coalesce=span_coalesce)
+        f = build_mpmd_executor(plan, sliced, params, mesh, batch=2)
     finally:
         segment.coalesce_windows = with_windows
     return np.asarray(f(x)), f.segment_stats
@@ -51,9 +50,7 @@ for s in stats:
         s["window_elems"] / s["gather_elems"] if s["gather_elems"] else 0.0)
 y_off, stats_off = run(windows=False)
 assert sum(s["window_elems"] for s in stats_off) == 0
-y_flat, _ = run(span_coalesce=False)
 assert (y == y_off).all()
-assert (y == y_flat).all()
 # interpret_plan convolves natively where the segmented kernels run
 # patches + GEMM on operand weights: equal up to float reassociation
 yi = np.asarray(interpret_plan(plan, sliced, params, x))
@@ -71,8 +68,8 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_executor_window_path_bit_identical(subproc, case):
     """The segmented executor with window gathers against itself with them
-    off, against ``span_coalesce=False`` and against ``interpret_plan``; on
-    1 or 4 virtual devices as the plan's m asks."""
+    off and against ``interpret_plan``; on 1 or 4 virtual devices as the
+    plan's m asks."""
     from repro.codegen.segment import MIN_WINDOW
 
     model, m = CASES[case]
